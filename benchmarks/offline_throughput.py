@@ -18,6 +18,8 @@ from repro.configs import get_config
 from repro.core import costmodel as cm
 from repro.core.autosearch import (autosearch, sequential_schedule,
                                    throughput_estimate)
+from repro.launch.runtime import (device_label, enable_compile_cache,
+                                  ensure_host_devices)
 from repro.models import model
 from repro.serving.config import EngineConfig
 from repro.serving.engine import ServeEngine
@@ -601,11 +603,11 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if args.tp_only and args.tp <= 1:
         ap.error("--tp-only needs --tp N with N > 1")
-    if args.tp > 1:
-        # before the first jax operation: importing jax does not initialize
-        # the backend, so the host-device flag still takes effect here
-        from repro.launch.serve import ensure_host_devices
-        ensure_host_devices(args.tp)
+    # before the first jax operation: importing jax does not initialize
+    # the backend, so the host-device flag still takes effect here
+    ensure_host_devices(args.tp)
+    enable_compile_cache()
+    dev = device_label()
     rows = run(engine_only=args.engine_only,
                base=EngineConfig.from_args(args), tp=args.tp,
                tp_only=args.tp_only, spec_only=args.spec_only,
@@ -629,7 +631,7 @@ def main(argv=None) -> None:
             if r["spec_acceptance_rate"] is not None:
                 spec = (f", accept {r['spec_acceptance_rate']}, "
                         f"{r['spec_accepted_per_verify']} tok/verify")
-            print(f"fig10/{r['case']},0.0,{r['tok_s_cpu']} tok/s CPU "
+            print(f"fig10/{r['case']},0.0,{r['tok_s_cpu']} tok/s {dev} "
                   f"({r['tokens']} tokens, {r['iters']} iters, "
                   f"{r['dispatches_per_iter']} disp/it, "
                   f"{r['host_syncs_per_iter']} sync/it, "
@@ -642,7 +644,7 @@ def main(argv=None) -> None:
                          f"{r['speedup_vs_bf16']}x tok/s vs bf16, "
                          f"greedy match {r['greedy_match_frac']}, "
                          f"drift {r['max_logit_drift_f32']}]")
-            print(f"fig10/{r['case']},0.0,{r['tok_s_cpu']} tok/s CPU "
+            print(f"fig10/{r['case']},0.0,{r['tok_s_cpu']} tok/s {dev} "
                   f"({r['tokens']} tokens, {r['iters']} iters, "
                   f"{r['dispatches_per_iter']} disp/it, "
                   f"{r['host_syncs_per_iter']} sync/it, "
@@ -657,7 +659,7 @@ def main(argv=None) -> None:
                 extra = (f" [{r['prefill_flops_ratio_vs_no_prefix']}x "
                          f"prefill FLOPs, {r['ttft_ratio_vs_no_prefix']}x "
                          f"TTFT vs no-prefix]")
-            print(f"fig10/{r['case']},0.0,{r['tok_s_cpu']} tok/s CPU "
+            print(f"fig10/{r['case']},0.0,{r['tok_s_cpu']} tok/s {dev} "
                   f"({r['tokens']} tokens, {r['iters']} iters, "
                   f"{r['dispatches_per_iter']} disp/it, "
                   f"{r['host_syncs_per_iter']} sync/it, "
@@ -686,7 +688,7 @@ def main(argv=None) -> None:
                             if "speedup_vs_tp1" in r else "") + "]")
             sweep = (f", kv sweep {r['attn_kv_sweep_frac']}x"
                      if r.get("attn_kv_sweep_frac") is not None else "")
-            print(f"fig10/{r['case']},0.0,{r['tok_s_cpu']} tok/s CPU "
+            print(f"fig10/{r['case']},0.0,{r['tok_s_cpu']} tok/s {dev} "
                   f"({r['tokens']} tokens, {r['iters']} iters, "
                   f"{r['dispatches_per_iter']} disp/it, "
                   f"{r['host_syncs_per_iter']} sync/it, "

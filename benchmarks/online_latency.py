@@ -28,6 +28,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.runtime import enable_compile_cache
 from repro.models import model
 from repro.serving.config import EngineConfig, PoolConfig
 from repro.serving.engine import ServeEngine
@@ -153,6 +154,7 @@ def main() -> None:
     ap.add_argument("--json", default=None, help="artifact path")
     ap.add_argument("--chaos-smoke", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.chaos_smoke:
         rows = [run_chaos_smoke(args.requests, args.rate, args.seed)]

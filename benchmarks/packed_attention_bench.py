@@ -32,6 +32,7 @@ try:
 except ImportError:                      # run directly, not as a module
     from common import emit, time_fn
 from repro.kernels import ops
+from repro.launch.runtime import enable_compile_cache
 from repro.serving.scheduler import default_kv_buckets
 
 
@@ -111,6 +112,7 @@ def main(argv=None) -> None:
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--json", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     rows = run(impl=args.impl, t=args.tokens, n_slots=args.slots,
                max_len=args.max_len, h=args.heads, kv=args.kv_heads,
                d_qk=args.d_qk, d_v=args.d_v, dtype=args.dtype)

@@ -10,6 +10,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.runtime import device_label
 from repro.models import model
 from repro.serving.config import EngineConfig
 from repro.serving.engine import ServeEngine
@@ -41,7 +42,7 @@ def main() -> None:
     st = eng.stats
     print(f"finished {len(done)}/{args.requests} in {st.iterations} iterations")
     print(f"tokens: {st.prefill_tokens} prefill + {st.decode_tokens} decode "
-          f"= {st.total_tokens} @ {st.throughput:.1f} tok/s (CPU ref path)")
+          f"= {st.total_tokens} @ {st.throughput:.1f} tok/s on {device_label()}")
     print(f"dense-batch histogram (discrete batching): "
           f"{dict(sorted(st.dense_batch_hist.items()))}")
     kv = eng.kv.stats

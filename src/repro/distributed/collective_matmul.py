@@ -17,12 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-# ``lax.pvary`` (varying-manual-axes tagging for shard_map's vma checks)
-# only exists on newer jax; on older releases there is no vma tracking and
-# the tag is a no-op.
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-
-
 def allgather_matmul(x: jax.Array, w: jax.Array, axis_name: str) -> jax.Array:
     """x: (m, k_local) — feature-sharded on `axis_name`;
     w: (k_total, n_local) — each device holds ALL rows for its column shard.
@@ -47,7 +41,10 @@ def allgather_matmul(x: jax.Array, w: jax.Array, axis_name: str) -> jax.Array:
             chunk, axis_name, [(j, (j - 1) % p) for j in range(p)])
         return acc, nxt, (src + 1) % p
 
-    acc = _pvary(jnp.zeros((m, w.shape[1]), jnp.float32), (axis_name,))
+    # the ring accumulator differs per device: tag it varying for
+    # shard_map's replication (vma) check
+    acc = jax.lax.pcast(jnp.zeros((m, w.shape[1]), jnp.float32),
+                        (axis_name,), to="varying")
     acc, chunk, src = jax.lax.fori_loop(0, p - 1, body, (acc, x, idx))
     acc = acc + jnp.dot(chunk, rows(src), preferred_element_type=jnp.float32)
     return acc.astype(x.dtype)
@@ -82,7 +79,8 @@ def matmul_reduce_scatter(x: jax.Array, w: jax.Array, axis_name: str,
         return nxt, (dst - 1) % p
 
     start = (idx - 1) % p
-    acc = _pvary(jnp.zeros((m, nc), jnp.float32), (axis_name,))
+    acc = jax.lax.pcast(jnp.zeros((m, nc), jnp.float32), (axis_name,),
+                        to="varying")
     acc, dst = jax.lax.fori_loop(0, p - 1, body, (acc, start))
     # dst == idx now: add our own contribution last
     acc = acc + jnp.dot(x, cols(dst), preferred_element_type=jnp.float32)

@@ -142,25 +142,3 @@ def shard(x: jax.Array, *axes: Optional[str]) -> jax.Array:
 def named_sharding(mesh: Mesh, *axes: Optional[str],
                    rules: Optional[dict[str, MeshAxes]] = None) -> NamedSharding:
     return NamedSharding(mesh, logical_to_pspec(tuple(axes), mesh, rules))
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check: bool = True):
-    """Version-adaptive ``shard_map``: newer jax exposes ``jax.shard_map``
-    (replication checking via ``check_vma``); older releases have
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``.  The
-    engine's TP packed step (DESIGN.md §11) disables the check — its body
-    mixes manually-replicated values with psum'd partials, which the
-    static replication tracker cannot prove."""
-    import inspect
-    try:
-        from jax.experimental.shard_map import shard_map as smap
-    except ImportError:
-        smap = jax.shard_map
-    kw = {}
-    if not check:
-        # fail loudly if a future jax renames the kwarg again (check_rep ->
-        # check_vma already happened once): with the check silently left on,
-        # the TP body would die in an opaque replication-check trace error
-        kw = {next(k for k in ("check_rep", "check_vma")
-                   if k in inspect.signature(smap).parameters): False}
-    return smap(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)

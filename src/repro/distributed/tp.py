@@ -59,11 +59,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import (ATTN, FFN_DENSE, FFN_MOE, FFN_MOE_DENSE,
                                 MAMBA, MLSTM, SLSTM, ModelConfig)
+from repro.core.nanobatch import nano_batch_sizes_for
 from repro.distributed.collective_matmul import matmul_allreduce
 
 # ---------------------------------------------------------------------------
@@ -99,6 +99,26 @@ def tp_ctx(axis: str, size: int, nano: tuple[int, ...] = ()):
 
 def active() -> Optional[TPContext]:
     return _STATE.ctx
+
+
+def shard_map_packed(body, mesh, nano: int, in_specs, out_specs):
+    """``shard_map(body)`` over the 1-D TP mesh: the one TP wrapping of a
+    packed forward (the engine's step and ``chip_smoke.py``'s teacher-forced
+    check both go through it).  ``body`` is traced under a ``tp_ctx`` whose
+    nano-batch split (``nano`` groups) comes from the static length of its
+    third operand, the ``(1, T)`` token stream — so the compile cache keys
+    only on the launch shape, and the nano-batch layout governs how the
+    row-parallel all-reduces are chunked.  The replication check is off:
+    the body mixes manually replicated values with psum'd partials, which
+    the static tracker cannot prove."""
+    size = int(mesh.shape["model"])
+
+    def traced(*args):
+        sizes = nano_batch_sizes_for(args[2].shape[1], nano).sizes
+        with tp_ctx("model", size, sizes):
+            return body(*args)
+    return jax.shard_map(traced, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def world() -> int:
@@ -219,15 +239,25 @@ def _needs_half_interleave(path: tuple[str, ...]) -> bool:
             or (path[-1] in ("w_in", "w_up") and "mixer" in path))
 
 
-def _interleave_halves(w: np.ndarray, p: int) -> np.ndarray:
-    c = w.shape[-1] // 2
-    blk = c // p
-    a, b = w[..., :c], w[..., c:]
-    parts = []
-    for j in range(p):
-        parts.append(a[..., j * blk:(j + 1) * blk])
-        parts.append(b[..., j * blk:(j + 1) * blk])
-    return np.concatenate(parts, axis=-1)
+def _interleave_halves(w, p: int):
+    """Columns ``[a | b]`` -> ``[a_0 b_0 | a_1 b_1 | ...]`` (p blocks each);
+    numpy or jax arrays alike."""
+    lead, n = w.shape[:-1], w.shape[-1]
+    return w.reshape(lead + (2, p, n // (2 * p))).swapaxes(-3, -2) \
+        .reshape(w.shape)
+
+
+def _interleave_fused(params: dict, p: int, path: tuple = ()) -> dict:
+    """Apply the fused-column re-interleave to the leaves that need it."""
+    out = {}
+    for k, v in params.items():
+        sub = path + (k,)
+        if isinstance(v, dict):
+            out[k] = _interleave_fused(v, p, sub)
+        else:
+            out[k] = _interleave_halves(v, p) \
+                if _needs_half_interleave(sub) else v
+    return out
 
 
 def param_pspecs_tp(cfg: ModelConfig) -> dict:
@@ -238,30 +268,48 @@ def param_pspecs_tp(cfg: ModelConfig) -> dict:
     return map_defs(_param_spec, model_defs(cfg, tp=1))
 
 
-def shard_params_tp(cfg: ModelConfig, params: dict, mesh) -> dict:
-    """Place a (replicated-layout) param tree on the TP mesh, applying the
-    fused-column re-interleave where the layout requires it."""
-    from repro.models.model import model_defs
-    defs = model_defs(cfg, tp=1)
+def _on_mesh(specs, mesh):
+    """PartitionSpec tree -> NamedSharding tree on ``mesh``."""
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                        is_leaf=lambda x: isinstance(x, P))
+
+
+def _param_shardings(cfg: ModelConfig, mesh) -> dict:
+    return _on_mesh(param_pspecs_tp(cfg), mesh)
+
+
+def init_params_tp(cfg: ModelConfig, key: jax.Array, mesh) -> dict:
+    """``model.init(cfg, key)`` made directly in the TP layout: each device
+    materializes only its own shards (random bits are partitionable), so
+    no device ever holds the whole model — the way a model that does not
+    fit one chip gets started.  Same values as ``shard_params_tp`` of the
+    unsharded init."""
+    from repro.models.model import init
     p = int(mesh.shape["model"])
+    return jax.jit(lambda k: _interleave_fused(init(cfg, k), p),
+                   out_shardings=_param_shardings(cfg, mesh))(key)
 
-    def walk(prm, dfs, path):
-        out = {}
-        for k, v in prm.items():
-            if isinstance(v, dict):
-                out[k] = walk(v, dfs[k], path + (k,))
-            else:
-                leaf_path = path + (k,)
-                # only the fused-projection leaves round-trip through the
-                # host (their columns must be re-interleaved); everything
-                # else reshards device-side
-                a = _interleave_halves(np.asarray(v), p) \
-                    if _needs_half_interleave(leaf_path) else v
-                spec = _param_spec(leaf_path, dfs[k])
-                out[k] = jax.device_put(a, NamedSharding(mesh, spec))
-        return out
 
-    return walk(params, defs, ())
+def shard_params_tp(cfg: ModelConfig, params: dict, mesh) -> dict:
+    """Place a param tree that came from the host (or one device) on the TP
+    mesh, applying the fused-column re-interleave where the layout
+    requires it."""
+    p = int(mesh.shape["model"])
+    return jax.device_put(_interleave_fused(params, p),
+                          _param_shardings(cfg, mesh))
+
+
+def place_params_tp(cfg: ModelConfig, params: dict, mesh) -> dict:
+    """The engine's entry: params already laid out on ``mesh`` (from
+    ``init_params_tp``) are used as they are; anything else goes through
+    ``shard_params_tp``."""
+    want = _param_shardings(cfg, mesh)
+    placed = jax.tree.map(
+        lambda a, s: isinstance(a, jax.Array)
+        and a.sharding.is_equivalent_to(s, a.ndim), params, want)
+    if all(jax.tree.leaves(placed)):
+        return params
+    return shard_params_tp(cfg, params, mesh)
 
 
 def _block_cache_specs(cfg: ModelConfig, spec,
@@ -307,18 +355,14 @@ def cache_pspecs_tp(cfg: ModelConfig, kv_dtype: str | None = None) -> list:
     return out
 
 
-def shard_cache_tp(cfg: ModelConfig, cache: list, mesh,
-                   kv_dtype: str | None = None) -> list:
-    specs = cache_pspecs_tp(cfg, kv_dtype)
-    out = []
-    for gi, group in enumerate(cache):
-        g = {}
-        for sub, leaves in group.items():
-            g[sub] = {name: jax.device_put(
-                leaf, NamedSharding(mesh, specs[gi][sub][name]))
-                for name, leaf in leaves.items()}
-        out.append(g)
-    return out
+def init_cache_tp(cfg: ModelConfig, batch: int, max_len: int, mesh,
+                  kv_dtype: str | None = None) -> list:
+    """``model.init_cache`` made directly in the TP layout (no device holds
+    the whole cache first)."""
+    from repro.models.model import init_cache
+    return jax.jit(lambda: init_cache(cfg, 1, batch, max_len, kv_dtype),
+                   out_shardings=_on_mesh(cache_pspecs_tp(cfg, kv_dtype),
+                                          mesh))()
 
 
 # ---------------------------------------------------------------------------

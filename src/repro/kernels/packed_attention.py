@@ -25,9 +25,10 @@ discarded by the existing length mask (scores) plus an explicit zero-mask on
 the value rows — no O(cache) ``jnp.pad`` on the hot path (DESIGN.md §15).
 
 int8 KV (DESIGN.md §15): when ``k_scale``/``v_scale`` are passed the k/v
-tiles DMA *as stored* (int8) together with a small per-row f32 scale tile
-(same index map, ~1/head_dim the bytes), and the kernel dequantizes
-in-register — ``k_i8 * scale`` in f32 — before the flash math.  Attention
+tiles DMA *as stored* (int8) together with a small ``(Bk, KV)`` f32 scale
+tile of every kv head (same slot/block index, ~KV/head_dim the bytes), and
+the kernel picks its head's column and dequantizes in-register —
+``k_i8 * scale`` in f32 — before the flash math.  Attention
 HBM traffic drops ~2× while the grid, the scalar-prefetch index maps and
 the online-softmax scratch are unchanged.
 
@@ -57,6 +58,7 @@ def _flash_step(len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref,
     and must be zeroed out of the value accumulation (their *scores* are
     already masked: kpos >= s_valid >= lengths[t])."""
     t = pl.program_id(0)
+    head = pl.program_id(1)
     sb = pl.program_id(2)
     nsb = pl.num_programs(2)
 
@@ -74,9 +76,8 @@ def _flash_step(len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref,
         k = k_ref[0, 0].astype(jnp.float32)                       # (Bk, Dqk)
         v = v_ref[0, 0].astype(jnp.float32)                       # (Bk, Dv)
         if ks_ref is not None:
-            k = k * ks_ref[0, 0][:, None]
-        if vs_ref is not None:
-            v = v * vs_ref[0, 0][:, None]
+            k = k * _head_scale(ks_ref, head)
+            v = v * _head_scale(vs_ref, head)
         if s_valid is not None:
             vrow = sb * block_k + \
                 jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
@@ -99,6 +100,17 @@ def _flash_step(len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref,
     def _finalize():
         denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
         o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+def _head_scale(s_ref, head):
+    """This grid step's kv-head column of a ``(1, Bk, KV)`` scale tile, as a
+    ``(Bk, 1)`` row scale.  The tile carries every head because a block's
+    last two dims must be (8, 128)-aligned or whole; a one-hot lane
+    reduction picks the head without a dynamic lane slice."""
+    sc = s_ref[0]                                                 # (Bk, KV)
+    lane = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+    return jnp.sum(jnp.where(lane == head, sc, 0.0), axis=1,
+                   keepdims=True)
 
 
 def _kernel(slot_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
@@ -205,15 +217,15 @@ def packed_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     operands = [qf, kf, vf]
     kernel = _kernel
     if k_scale is not None:
-        ksf = k_scale.transpose(0, 2, 1)      # (N, KV, S)
-        vsf = v_scale.transpose(0, 2, 1)
+        # scales stay (N, S, KV): a (1, Bk, KV) tile is layout-legal where a
+        # (1, 1, Bk) tile of the head-major transpose is not
         in_specs += [
-            pl.BlockSpec((1, 1, block_k),
-                         lambda ti, kv, sb, slot, ln: (slot[ti], kv, sb)),
-            pl.BlockSpec((1, 1, block_k),
-                         lambda ti, kv, sb, slot, ln: (slot[ti], kv, sb)),
+            pl.BlockSpec((1, block_k, kvh),
+                         lambda ti, kv, sb, slot, ln: (slot[ti], sb, 0)),
+            pl.BlockSpec((1, block_k, kvh),
+                         lambda ti, kv, sb, slot, ln: (slot[ti], sb, 0)),
         ]
-        operands += [ksf, vsf]
+        operands += [k_scale, v_scale]
         kernel = _kernel_quant
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                # token_slot, lengths
@@ -233,6 +245,7 @@ def packed_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, kvh, group, dv), q.dtype),
         interpret=interpret,
+        name="packed_attention",
     )(token_slot, lengths, *operands)
     return out.reshape(t, h, dv)
 
@@ -276,15 +289,15 @@ def _packed_attention_block(q, k_cache, v_cache, token_slot, lengths,
     operands = [qf, kf, vf]
     kernel = _kernel_block
     if k_scale is not None:
-        ksf = k_scale.reshape(n * nb_cols, bs, kvh).transpose(0, 2, 1)
-        vsf = v_scale.reshape(n * nb_cols, bs, kvh).transpose(0, 2, 1)
+        ksf = k_scale.reshape(n * nb_cols, bs, kvh)      # (NB, bs, KV)
+        vsf = v_scale.reshape(n * nb_cols, bs, kvh)
         in_specs += [
-            pl.BlockSpec((1, 1, bs),
+            pl.BlockSpec((1, bs, kvh),
                          lambda ti, kv, sb, bt, slot, ln:
-                         (bt[slot[ti] * nb_cols + sb], kv, 0)),
-            pl.BlockSpec((1, 1, bs),
+                         (bt[slot[ti] * nb_cols + sb], 0, 0)),
+            pl.BlockSpec((1, bs, kvh),
                          lambda ti, kv, sb, bt, slot, ln:
-                         (bt[slot[ti] * nb_cols + sb], kv, 0)),
+                         (bt[slot[ti] * nb_cols + sb], 0, 0)),
         ]
         operands += [ksf, vsf]
         kernel = _kernel_block_quant
@@ -305,5 +318,6 @@ def _packed_attention_block(q, k_cache, v_cache, token_slot, lengths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, kvh, group, dv), q.dtype),
         interpret=interpret,
+        name="packed_attention",
     )(bt, token_slot, lengths, *operands)
     return out.reshape(t, h, dv)

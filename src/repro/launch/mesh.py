@@ -18,16 +18,17 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 def make_tp_mesh(tp: int):
     """1-D tensor-parallel mesh over the first ``tp`` local devices (the
-    serving engine's ``ServeEngine(tp=N)`` mesh, DESIGN.md §11).  On this
-    CPU container the devices come from
+    serving engine's ``ServeEngine(tp=N)`` mesh, DESIGN.md §11).  On a TPU
+    host these are chips; a CPU run (``JAX_PLATFORMS=cpu``) gets them from
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``."""
     import numpy as np
     devs = jax.devices()
     if len(devs) < tp:
         raise RuntimeError(
-            f"tp={tp} needs {tp} devices but only {len(devs)} are visible; "
-            "set XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{tp} (host-platform devices) or run on a {tp}-chip slice")
+            f"tp={tp} needs {tp} devices but only {len(devs)} "
+            f"{devs[0].platform} devices are visible; run on a {tp}-chip "
+            "host, or on the CPU with JAX_PLATFORMS=cpu and XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={tp}")
     return jax.sharding.Mesh(np.array(devs[:tp]), ("model",))
 
 

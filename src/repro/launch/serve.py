@@ -6,32 +6,21 @@
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, scale_down
+from repro.distributed import tp as tp_lib
+from repro.launch.mesh import make_tp_mesh
+from repro.launch.runtime import (device_label, enable_compile_cache,
+                                  ensure_host_devices)
 from repro.models import model as model_lib
 from repro.serving.config import EngineConfig, PoolConfig
 from repro.serving.engine import ServeEngine
 from repro.serving.pool import ReplicaPool
 from repro.serving.request import Request
-
-
-def ensure_host_devices(n: int) -> None:
-    """Give this process ``n`` host-platform devices for ``--tp n`` runs on
-    CPU.  Importing jax doesn't initialize the backend, so appending the
-    flag first thing in main() — before any jax *operation* — is enough; if
-    the backend somehow initialized earlier with too few devices,
-    ``make_tp_mesh`` raises the actionable error."""
-    if n <= 1:
-        return
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n}").strip()
 
 
 def make_requests(n: int, vocab: int, seed: int = 0, p_mean: int = 24,
@@ -74,7 +63,7 @@ def serve_pool(args, cfg, params, pcfg, reqs) -> None:
     print(f"pool[{pcfg.replicas} replicas]: finished {len(results)}"
           f"/{len(reqs)} requests, {snap['shed_requests']} shed, "
           f"{n_tok} tokens in {wall*1e3:.0f} ms "
-          f"({n_tok / max(wall, 1e-9):.1f} tok/s)")
+          f"({n_tok / max(wall, 1e-9):.1f} tok/s on {device_label()})")
     print(f"fault tolerance: {snap['faults_injected']} faults injected, "
           f"{snap['redispatched_requests']} requests re-dispatched "
           f"({snap['redispatched_tokens']} committed tokens replayed), "
@@ -114,11 +103,18 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     ensure_host_devices(args.tp)     # before the first jax operation
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = scale_down(cfg)
-    params = model_lib.init(cfg, jax.random.PRNGKey(args.seed))
+    key = jax.random.PRNGKey(args.seed)
+    if args.tp > 1:
+        # made in the TP layout, so a model larger than one device starts
+        tp_lib.validate_tp(cfg, args.tp)
+        params = tp_lib.init_params_tp(cfg, key, make_tp_mesh(args.tp))
+    else:
+        params = model_lib.init(cfg, key)
     pcfg = PoolConfig.from_args(args)
     reqs = make_requests(args.requests, cfg.vocab_size, args.seed)
 
@@ -169,7 +165,7 @@ def main() -> None:
           f"{st['iterations']} iters")
     print(f"tokens: prefill {st['prefill_tokens']} decode "
           f"{st['decode_tokens']} total {st['total_tokens']}")
-    print(f"throughput {st['throughput']:.1f} tok/s (CPU ref-path proxy)")
+    print(f"throughput {st['throughput']:.1f} tok/s on {device_label()}")
     print(f"step={eng.step_mode}: {st['dispatches_per_iter']:.2f} "
           f"dispatches/iter, {st['syncs_per_iter']:.2f} host syncs/iter, "
           f"{st['packed_pad_tokens']} pad tokens")

@@ -200,8 +200,8 @@ class EngineConfig:
         ap.add_argument("--tp", type=int, default=1,
                         help="tensor-parallel degree (DESIGN.md §11): the "
                              "packed step runs as one shard_map program over "
-                             "a 1-D model mesh; on CPU the devices come from "
-                             "--xla_force_host_platform_device_count")
+                             "a 1-D model mesh of chips; with "
+                             "JAX_PLATFORMS=cpu, of host-platform devices")
         ap.add_argument("--no-kv-bucketing", action="store_true",
                         help="sweep max_len every iteration instead of the "
                              "KV-length bucket (DESIGN.md §9; A/B baseline)")

@@ -62,8 +62,9 @@ paper's §4.3 network/compute overlap as real launched collectives.  The
 still exactly one dispatch + one (deferred) sync and ``async_depth``
 composes unchanged; the compile cache keeps the
 (|T buckets| + 1) × |kv buckets| bound per mesh.  ``tp=1`` is exactly the
-single-device path; on this CPU container the mesh comes from
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` and the
+single-device path.  On a TPU host the mesh is the first N chips; a CPU
+run (``JAX_PLATFORMS=cpu``) gets N host devices from
+``XLA_FLAGS=--xla_force_host_platform_device_count=N``, and there the
 *intra*-device overlap is still modeled by core/autosearch.
 """
 from __future__ import annotations
@@ -80,9 +81,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ATTN, ModelConfig
-from repro.core.nanobatch import nano_batch_sizes_for
 from repro.distributed import tp as tp_lib
-from repro.distributed.sharding import shard_map_compat
 from repro.kernels import ops
 from repro.launch.mesh import make_tp_mesh
 from repro.models import blocks
@@ -412,9 +411,21 @@ class ServeEngine:
             max_request_len=self.max_len, spec_k=self.spec_k,
             drafter=self.drafter)
 
+        # tensor parallelism (DESIGN.md §11): 1-D ("model",) mesh, params
+        # and slot caches placed with the manual shard_map layout (fused
+        # x‖z / u‖g projection columns re-interleaved so each shard holds
+        # matching halves; caches made sharded, never whole on one device);
+        # the last_token / cache_len buffers stay replicated so the §10
+        # feedback loop closes without a collective
+        self.tp = int(config.tp)
+        self._mesh = None
+        if self.tp > 1:
+            tp_lib.validate_tp(cfg, self.tp)
+            self._mesh = make_tp_mesh(self.tp)
+            self.params = tp_lib.place_params_tp(cfg, self.params, self._mesh)
+
         # slot caches: model cache trees with leading batch = max_slots
-        self.cache = model_lib.init_cache(cfg, 1, self.max_slots,
-                                          self.max_len, self._cache_kv_dtype)
+        self.cache = self._new_cache(self.max_slots)
         self.cache_len = jnp.zeros((self.max_slots,), jnp.int32)
         # device-resident sampled-token feedback (DESIGN.md §10), generalized
         # to the per-slot token ring (§13): row = the W = spec_k+1 samples of
@@ -440,30 +451,14 @@ class ServeEngine:
 
         # fresh one-slot cache, scattered into a slot on (re)assignment so a
         # reused slot never leaks the previous request's recurrent state
-        self._slot_init = model_lib.init_cache(cfg, 1, 1, self.max_len,
-                                               self._cache_kv_dtype)
+        self._slot_init = self._new_cache(1)
 
-        # tensor parallelism (DESIGN.md §11): 1-D ("model",) mesh, params
-        # and slot caches placed with the manual shard_map layout (fused
-        # x‖z / u‖g projection columns re-interleaved so each shard holds
-        # matching halves); the last_token / cache_len buffers stay
-        # replicated so the §10 feedback loop closes without a collective
-        self.tp = int(config.tp)
-        self._mesh = None
         # modeled collective wire bytes per launched token (linear in T):
         # resolved once here so the per-iteration stats update off the §10
         # host hot path is a single multiply
         self._tp_iter_bytes = tp_lib.collective_bytes_per_iter(
             cfg, 1, self.tp, jnp.dtype(cfg.dtype).itemsize)
         if self.tp > 1:
-            tp_lib.validate_tp(cfg, self.tp)
-            self._mesh = make_tp_mesh(self.tp)
-            self.params = tp_lib.shard_params_tp(cfg, self.params, self._mesh)
-            self.cache = tp_lib.shard_cache_tp(cfg, self.cache, self._mesh,
-                                               self._cache_kv_dtype)
-            self._slot_init = tp_lib.shard_cache_tp(cfg, self._slot_init,
-                                                    self._mesh,
-                                                    self._cache_kv_dtype)
             rep = NamedSharding(self._mesh, P())
             self.cache_len = jax.device_put(self.cache_len, rep)
             self.last_token = jax.device_put(self.last_token, rep)
@@ -497,6 +492,13 @@ class ServeEngine:
         # one compiled program per bucketed chunk length (scheduler-quantized)
         self._prefill_step = jax.jit(self._prefill_impl, donate_argnums=(1,))
         self._reset_step = jax.jit(_reset_slot, donate_argnums=(0,))
+
+    def _new_cache(self, slots: int) -> list:
+        if self._mesh is None:
+            return model_lib.init_cache(self.cfg, 1, slots, self.max_len,
+                                        self._cache_kv_dtype)
+        return tp_lib.init_cache_tp(self.cfg, slots, self.max_len,
+                                    self._mesh, self._cache_kv_dtype)
 
     # ---- jitted decode over all slots (static shapes) -----------------------
     def _decode_impl(self, params, cache, tokens, cache_len, active):
@@ -672,14 +674,13 @@ class ServeEngine:
 
     def _build_packed_tp_step(self):
         """jit(shard_map(packed body)) over the 1-D TP mesh (DESIGN.md
-        §11).  The body is ``_packed_core`` unchanged, traced under a
-        ``tp_ctx`` whose nano split comes from the (static) launch length —
-        so the compile cache still keys only on (T bucket, kv bucket), and
-        the nano-batch layout governs how the row-parallel all-reduces are
-        chunked.  Returns a callable with the tp=1 step's signature (the
-        fresh-slot cache is injected as a shard_map operand here; carries
-        ``_cache_size`` for the compile-cache-bound assertions)."""
-        mesh = self._mesh
+        §11).  The body is ``_packed_core`` unchanged, wrapped by
+        ``tp.shard_map_packed`` (a ``tp_ctx`` whose nano split comes from
+        the static launch length) — so the compile cache still keys only
+        on (T bucket, kv bucket).  Returns a callable with the tp=1 step's
+        signature (the fresh-slot cache is injected as a shard_map operand
+        here; carries ``_cache_size`` for the compile-cache-bound
+        assertions)."""
         param_specs = tp_lib.param_pspecs_tp(self.cfg)
         cache_specs = tp_lib.cache_pspecs_tp(self.cfg, self._cache_kv_dtype)
         rep = P()
@@ -690,28 +691,12 @@ class ServeEngine:
         in_specs = (param_specs, cache_specs) + (rep,) * 14 + (cache_specs,)
         out_specs = (rep, cache_specs, rep, rep, rep)
 
-        def entry(params, cache, tokens, token_slot, token_pos,
-                  token_active, cache_len, reset, last_token, accept_len,
-                  from_last, sample_slot, verify_idx, token_rid, token_dst,
-                  block_tables, slot_init, kv_bucket):
-            def body(params, cache, tokens, token_slot, token_pos,
-                     token_active, cache_len, reset, last_token, accept_len,
-                     from_last, sample_slot, verify_idx, token_rid,
-                     token_dst, block_tables, slot_init):
-                nano = nano_batch_sizes_for(tokens.shape[1], self.nano).sizes
-                with tp_lib.tp_ctx("model", self.tp, nano):
-                    return self._packed_core(
-                        params, cache, tokens, token_slot, token_pos,
-                        token_active, cache_len, reset, last_token,
-                        accept_len, from_last, sample_slot, verify_idx,
-                        token_rid, token_dst, block_tables, slot_init,
-                        kv_bucket)
-            return shard_map_compat(body, mesh, in_specs, out_specs,
-                                    check=False)(
-                params, cache, tokens, token_slot, token_pos, token_active,
-                cache_len, reset, last_token, accept_len, from_last,
-                sample_slot, verify_idx, token_rid, token_dst, block_tables,
-                slot_init)
+        def entry(*args):
+            # args: the 17 operands of _packed_core, then the static bucket
+            *operands, kv_bucket = args
+            return tp_lib.shard_map_packed(
+                lambda *a: self._packed_core(*a, kv_bucket), self._mesh,
+                self.nano, in_specs, out_specs)(*operands)
 
         jitted = jax.jit(entry, donate_argnums=(1, 8, 9),
                          static_argnums=(17,))

@@ -22,22 +22,12 @@ from repro.models import model
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
-# version-adaptive shard_map (check_rep/check_vma across jax releases):
-# the ONE implementation lives in repro.distributed.sharding — the engine's
-# TP packed step (DESIGN.md §11) uses it too, and subprocess snippets run
-# with PYTHONPATH=src.  Prepended to every subprocess snippet.
-SMAP_COMPAT = """
-    from repro.distributed.sharding import shard_map_compat as smap
-"""
-
-
 def run_subprocess(code: str, devices: int = 8) -> str:
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH=SRC)
     out = subprocess.run(
-        [sys.executable, "-c",
-         textwrap.dedent(SMAP_COMPAT) + textwrap.dedent(code)],
+        [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True, text=True, env=env, timeout=560)
     assert out.returncode == 0, out.stderr[-3000:]
     return out.stdout
@@ -53,15 +43,20 @@ def test_collective_matmuls_multi_device():
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.normal(size=(16, 64)), jnp.float32)
         w = jnp.asarray(rng.normal(size=(64, 32)), jnp.float32)
-        f = jax.jit(smap(lambda a, b: allgather_matmul(a, b, "model"),
-            mesh, (P(None, "model"), P(None, "model")), P(None, "model")))
+        f = jax.jit(jax.shard_map(
+            lambda a, b: allgather_matmul(a, b, "model"), mesh=mesh,
+            in_specs=(P(None, "model"), P(None, "model")),
+            out_specs=P(None, "model")))
         assert float(jnp.abs(f(x, w) - x @ w).max()) < 1e-4
-        g = jax.jit(smap(lambda a, b: matmul_reduce_scatter(a, b, "model"),
-            mesh, (P(None, "model"), P("model", None)), P(None, "model")))
+        g = jax.jit(jax.shard_map(
+            lambda a, b: matmul_reduce_scatter(a, b, "model"), mesh=mesh,
+            in_specs=(P(None, "model"), P("model", None)),
+            out_specs=P(None, "model")))
         assert float(jnp.abs(g(x, w) - x @ w).max()) < 1e-4
-        h = jax.jit(smap(lambda a, b: matmul_allreduce(a, b, "model"),
-            mesh, (P(None, "model"), P("model", None)), P(None, None),
-            check=False))
+        h = jax.jit(jax.shard_map(
+            lambda a, b: matmul_allreduce(a, b, "model"), mesh=mesh,
+            in_specs=(P(None, "model"), P("model", None)),
+            out_specs=P(None, None), check_vma=False))
         assert float(jnp.abs(h(x, w) - x @ w).max()) < 1e-4
         print("OK")
     """)
@@ -104,6 +99,49 @@ def test_tp_engine_token_equivalence_subprocess():
     assert "OK" in out
 
 
+@pytest.mark.parametrize("arch", ["tiny-toy", "xlstm-1.3b",
+                                  "jamba-1.5-large-398b"])
+def test_init_params_tp_matches_host_sharding(arch):
+    """Params made directly in the TP layout (no device ever holds the
+    whole model) equal the host init placed by ``shard_params_tp``,
+    fused-column re-interleave included, and the engine takes them as they
+    are: same tokens as from the host-layout params."""
+    out = run_subprocess(f"""
+        import jax, numpy as np
+        from repro.configs import get_config, scale_down
+        from repro.distributed import tp
+        from repro.launch.mesh import make_tp_mesh
+        from repro.models import model
+        from repro.serving.engine import ServeEngine
+        from repro.serving.request import Request
+
+        cfg = get_config("{arch}")
+        cfg = cfg if cfg.name == "tiny-toy" else scale_down(cfg)
+        mesh = make_tp_mesh(2)
+        key = jax.random.PRNGKey(3)
+        # jitted like init_params_tp: eager init differs by f32 ulps in
+        # the mamba a_log / dt_bias transcendentals
+        host = jax.jit(lambda k: model.init(cfg, k))(key)
+        direct = tp.init_params_tp(cfg, key, mesh)
+        placed = tp.shard_params_tp(cfg, host, mesh)
+        for a, b in zip(jax.tree.leaves(direct), jax.tree.leaves(placed)):
+            assert a.sharding.is_equivalent_to(b.sharding, a.ndim)
+            assert len(a.sharding.device_set) == 2
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert tp.place_params_tp(cfg, direct, mesh) is direct
+        outs = []
+        for params in (host, direct):
+            eng = ServeEngine(cfg, params, max_slots=2, max_len=32,
+                              discrete_sizes=(16, 8), avg_decode_len=4,
+                              tp=2)
+            eng.submit(Request(rid=0, prompt=[5, 6, 7, 8], max_new_tokens=3))
+            outs.append(tuple(eng.run()[0].output))
+        assert outs[0] == outs[1], outs
+        print("OK")
+    """, devices=2)
+    assert "OK" in out
+
+
 def test_compressed_psum_error_feedback():
     out = run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
@@ -117,8 +155,9 @@ def test_compressed_psum_error_feedback():
         def exchange(gs, rs):
             return compressed_psum({"w": gs}, {"w": rs}, "pod")
 
-        f = jax.jit(smap(exchange, mesh,
-            (P("pod"), P("pod")), (P("pod"), P("pod")), check=False))
+        f = jax.jit(jax.shard_map(
+            exchange, mesh=mesh, in_specs=(P("pod"), P("pod")),
+            out_specs=(P("pod"), P("pod")), check_vma=False))
         # accumulated compressed means track the true mean (error feedback)
         true_mean = np.asarray(g).mean(axis=0)
         res = jnp.zeros_like(g)
