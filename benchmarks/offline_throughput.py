@@ -451,9 +451,8 @@ def engine_spec_ab(n_requests: int = 10, spec_k: int = 4,
 
 def _kvdtype_logit_drift(cfg, max_len: int = 48) -> float:
     """Teacher-forced packed forward, native vs int8 cache, f32 weights —
-    the max |Δlogit| sample reported in the A/B (and stashed into
-    ``EngineStats.kv_quant_drift``).  f32 isolates quantization drift from
-    bf16 accumulation noise."""
+    the max |Δlogit| sample reported in the A/B.  f32 isolates quantization
+    drift from bf16 accumulation noise."""
     import jax.numpy as jnp
     fcfg = dataclasses.replace(cfg, dtype="float32")
     params = model.init(fcfg, jax.random.PRNGKey(0))
@@ -508,8 +507,6 @@ def engine_kvdtype_ab(n_requests: int = 10,
         _submit_workload(eng, name, p, d, n_requests, cfg.vocab_size,
                          n_requests)
         done = eng.run()
-        if kd == "int8":
-            eng.stats.kv_quant_drift = drift
         st = eng.stats.snapshot()
         outs[kd] = {r.rid: tuple(r.output) for r in done}
         tokens = st["total_tokens"] - warm["total_tokens"]

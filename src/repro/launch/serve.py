@@ -36,6 +36,16 @@ def make_requests(n: int, vocab: int, seed: int = 0, p_mean: int = 24,
     return out
 
 
+def queue_wait(done: list[Request]) -> str:
+    """Queue wait (first slot minus arrival) at p50 and p90."""
+    wait = [r.scheduled_at - r.arrival for r in done
+            if r.scheduled_at is not None]
+    if not wait:
+        return ""
+    return (f", queue wait p50 {np.percentile(wait, 50)*1e3:.1f} ms "
+            f"p90 {np.percentile(wait, 90)*1e3:.1f} ms")
+
+
 def serve_pool(args, cfg, params, pcfg, reqs) -> None:
     """Multi-replica path (DESIGN.md §14): N engines behind the router,
     driven by the pool event loop, with optional chaos injection."""
@@ -58,12 +68,18 @@ def serve_pool(args, cfg, params, pcfg, reqs) -> None:
     wall = time.perf_counter() - t0
 
     snap = pool.snapshot()
-    n_tok = sum(h.engine.stats.total_tokens
-                for h in pool.router.replicas if h.engine is not None)
+    engines = [h.engine for h in pool.router.replicas if h.engine is not None]
+    n_tok = sum(e.stats.total_tokens for e in engines)
+    compiles: dict = {}
+    for e in engines:
+        for k, n in e.stats.compile_hist.items():
+            compiles[k] = compiles.get(k, 0) + n
     print(f"pool[{pcfg.replicas} replicas]: finished {len(results)}"
           f"/{len(reqs)} requests, {snap['shed_requests']} shed, "
           f"{n_tok} tokens in {wall*1e3:.0f} ms "
           f"({n_tok / max(wall, 1e-9):.1f} tok/s on {device_label()})")
+    print(f"compiles in dispatch by (launch tokens, kv bucket): "
+          f"{dict(sorted(compiles.items()))}")
     print(f"fault tolerance: {snap['faults_injected']} faults injected, "
           f"{snap['redispatched_requests']} requests re-dispatched "
           f"({snap['redispatched_tokens']} committed tokens replayed), "
@@ -81,7 +97,7 @@ def serve_pool(args, cfg, params, pcfg, reqs) -> None:
            if r.finished_at is not None]
     if lat and args.online:
         print(f"latency: p50 {np.percentile(lat, 50)*1e3:.1f} ms "
-              f"p99 {np.percentile(lat, 99)*1e3:.1f} ms")
+              f"p99 {np.percentile(lat, 99)*1e3:.1f} ms" + queue_wait(done))
     for r in pool.shed[:5]:
         print(f"  shed rid={r.rid}: {r.reject_reason}")
 
@@ -165,7 +181,11 @@ def main() -> None:
           f"{st['iterations']} iters")
     print(f"tokens: prefill {st['prefill_tokens']} decode "
           f"{st['decode_tokens']} total {st['total_tokens']}")
-    print(f"throughput {st['throughput']:.1f} tok/s on {device_label()}")
+    print(f"throughput {st['throughput']:.1f} tok/s on {device_label()} "
+          f"(compiles left out)")
+    print(f"compiles in dispatch: {st['compiles']} "
+          f"({st['compile_time']:.2f} s) by (launch tokens, kv bucket): "
+          f"{dict(sorted(st['compile_hist'].items()))}")
     print(f"step={eng.step_mode}: {st['dispatches_per_iter']:.2f} "
           f"dispatches/iter, {st['syncs_per_iter']:.2f} host syncs/iter, "
           f"{st['packed_pad_tokens']} pad tokens")
@@ -204,13 +224,10 @@ def main() -> None:
                   f"{st['spec_accepted_per_verify']:.2f} committed "
                   f"tokens/verify)")
     if eng.kv_dtype == "int8":
-        drift = st["kv_quant_drift"]
         print(f"kv dtype int8: {st['kv_quant_bytes_saved']/1e6:.2f} MB of "
               f"cache writes saved vs {eng.cfg.dtype} storage "
               f"({eng.kv.bytes_per_token} B/token vs "
-              f"{eng._kv_bytes_native} B/token)"
-              + (f", max logit drift {drift:.4f}" if drift is not None
-                 else ""))
+              f"{eng._kv_bytes_native} B/token)")
     if eng.prefix_caching:
         total_prompt = sum(r.prompt_len for r in done)
         print(f"prefix caching: {kv['prefix_hit_tokens']} prompt tokens "
@@ -224,7 +241,8 @@ def main() -> None:
     if lat and args.online:
         norm = [l / max(len(r.output), 1) for l, r in zip(lat, done)]
         print(f"normalized latency: p50 {np.percentile(norm, 50)*1e3:.1f} ms/tok "
-              f"p99 {np.percentile(norm, 99)*1e3:.1f} ms/tok")
+              f"p99 {np.percentile(norm, 99)*1e3:.1f} ms/tok"
+              + queue_wait(done))
 
 
 if __name__ == "__main__":

@@ -70,6 +70,7 @@ run (``JAX_PLATFORMS=cpu``) gets N host devices from
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 import warnings
 from collections import deque
@@ -88,6 +89,7 @@ from repro.models import blocks
 from repro.models import model as model_lib
 from repro.serving import draft as draft_lib
 from repro.serving import sampling
+from repro.serving import spans
 from repro.serving.config import EngineConfig
 from repro.serving.kvcache import PagedKVManager
 from repro.serving.request import Request, State
@@ -176,11 +178,15 @@ class EngineStats:
     evacuated_tokens: int = 0
     # int8 KV quantization (DESIGN.md §15): cache bytes saved vs the
     # native-dtype layout for every token row written (counted at launch —
-    # tokens × (native rate − quantized rate)), and the most recent measured
-    # logit-drift sample (filled by benchmarks/tests that run the bf16 A/B;
-    # the engine itself never pays for a second forward)
+    # tokens × (native rate − quantized rate))
     kv_quant_bytes_saved: int = 0
-    kv_quant_drift: Optional[float] = None
+    # programs compiled (or loaded from the persistent compile cache) while
+    # a packed step was dispatched, their seconds, and how many fell on each
+    # (launch tokens, kv bucket) key
+    compiles: int = 0
+    compile_time: float = 0.0
+    compile_hist: dict[tuple[int, int], int] = dataclasses.field(
+        default_factory=dict)
 
     @property
     def total_tokens(self) -> int:
@@ -188,7 +194,9 @@ class EngineStats:
 
     @property
     def throughput(self) -> float:
-        return self.total_tokens / self.wall_time if self.wall_time else 0.0
+        """Tokens over the wall time spent serving, compiles left out."""
+        serving = self.wall_time - self.compile_time
+        return self.total_tokens / serving if serving > 0 else 0.0
 
     @property
     def prefill_expansion(self) -> float:
@@ -243,9 +251,28 @@ class EngineStats:
                for f in dataclasses.fields(self)}
         out["dense_batch_hist"] = dict(self.dense_batch_hist)
         out["kv_bucket_hist"] = dict(self.kv_bucket_hist)
+        out["compile_hist"] = dict(self.compile_hist)
         for name in self._DERIVED:
             out[name] = getattr(self, name)
         return out
+
+
+# A compile fires synchronously inside the jitted call that needs it, so the
+# one listener charges it to whichever packed dispatch this thread is in.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_dispatching = threading.local()
+
+
+def _on_compile(event: str, duration: float, **_) -> None:
+    at = getattr(_dispatching, "at", None)
+    if event == _COMPILE_EVENT and at is not None:
+        stats, key = at
+        stats.compiles += 1
+        stats.compile_time += duration
+        stats.compile_hist[key] = stats.compile_hist.get(key, 0) + 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile)
 
 
 @dataclasses.dataclass
@@ -258,6 +285,7 @@ class _InFlight:
     sample_at: list              # (rid, slot, kind) triples; kind is
     #                              "decode" | "verify" | "prefill"
     tokens: jax.Array            # payload handle, not yet transferred
+    iteration: int               # stats.iterations at launch
 
 
 def _to_token(v) -> int:
@@ -887,6 +915,7 @@ class ServeEngine:
         return finished
 
     # ---- packed iteration: one dispatch, one (deferred) host sync -----------
+    @spans.spanned(spans.RETIRE)
     def _retire_oldest(self) -> list[Request]:
         """Transfer the oldest in-flight iteration's payload (the deferred
         sync — blocking only if the device hasn't caught up), commit its
@@ -895,7 +924,8 @@ class ServeEngine:
         first ``accept_len`` ring entries are the committed run (§13);
         other sample points read ring column 0 (their accept_len is 1)."""
         inf = self._ring.popleft()
-        payload = self._fetch(inf.tokens)        # (max_slots, W + 1)
+        with spans.span(spans.FETCH, iteration=inf.iteration):
+            payload = self._fetch(inf.tokens)    # (max_slots, W + 1)
         t1 = time.perf_counter()
         W = self.spec_k + 1
         sampled: dict[int, object] = {}
@@ -944,175 +974,187 @@ class ServeEngine:
     def _launch_packed(self, plan: BatchPlan) -> _InFlight:
         t_host = time.perf_counter()
         packed = self.scheduler.pack(plan, nano=self.nano)
-        W = self.spec_k + 1
-        reset = np.zeros((self.max_slots,), bool)
-        for seg in packed.segments:
-            r = seg.req
-            if r.slot < 0:
-                assert self.slot_free, "scheduler admitted beyond slot capacity"
-                r.slot = self.slot_free.pop()
-                reset[r.slot] = True
-                self._pos[r.slot] = 0
+        with spans.span(spans.OPERANDS):
+            W = self.spec_k + 1
+            reset = np.zeros((self.max_slots,), bool)
+            for seg in packed.segments:
+                r = seg.req
+                if r.slot < 0:
+                    assert self.slot_free, \
+                        "scheduler admitted beyond slot capacity"
+                    r.slot = self.slot_free.pop()
+                    reset[r.slot] = True
+                    self._pos[r.slot] = 0
+                    if r.scheduled_at is None:
+                        r.scheduled_at = self._clock()
 
-        bs = self.kv.page_size
-        oob = self.max_slots * self.max_len
-        if self.prefix_caching:
-            # decode writes land at pos = _pos[slot] .. _pos[slot]+W-1 (not
-            # yet advanced; the worst case under speculation): grow each
-            # decoding request's block table NOW, launch-side, so the write
-            # targets exist before the (possibly deferred-commit)
-            # ``extend`` ever runs (DESIGN.md §12)
+            bs = self.kv.page_size
+            oob = self.max_slots * self.max_len
+            if self.prefix_caching:
+                # decode writes land at pos = _pos[slot] .. _pos[slot]+W-1 (not
+                # yet advanced; the worst case under speculation): grow each
+                # decoding request's block table NOW, launch-side, so the write
+                # targets exist before the (possibly deferred-commit)
+                # ``extend`` ever runs (DESIGN.md §12)
+                for seg in packed.segments:
+                    if seg.is_decode:
+                        self.kv.ensure(seg.req.rid,
+                                       int(self._pos[seg.req.slot]) + W)
+
+            t_total = packed.launch_tokens
+            tokens = np.zeros((t_total,), np.int32)
+            slot = np.zeros((t_total,), np.int32)
+            pos = np.zeros((t_total,), np.int32)
+            active = np.zeros((t_total,), bool)
+            # decode positions take the ring's newest accepted token on device
+            # (§10/§13): the host writes a placeholder and never needs the
+            # sampled value
+            from_last = np.zeros((t_total,), bool)
+            # block-table operands (prefix mode): per-token flat scatter target
+            # (OOB = dropped write, covers padding) and per-slot block tables
+            token_dst = np.full((t_total,), oob, np.int64)
+            tables_arr = np.zeros((self.max_slots, self._nb_cols), np.int32)
+            # per-slot verify stream positions (§13); OOB rows (== t_total)
+            # mark slots with no verify segment this iteration
+            verify_idx = np.full((self.max_slots, W), t_total, np.int32)
+            # per-token request id: the stochastic sampler's PRNG identity
+            # (sampling.packed_keys folds (rid, pos) — slot- and
+            # launch-independent); dead under greedy
+            rid_arr = np.zeros((t_total,), np.int32)
+            sample_at: list[tuple[int, int, str]] = []   # (rid, slot, kind)
+            t = 0
+            for seg in packed.segments:
+                r = seg.req
+                tbl = None
+                if self.prefix_caching:
+                    tbl = np.asarray(self.kv.table(r.rid), np.int64)
+                    # the allocator sizes tables by *predicted* length
+                    # (prompt + avg_decode), which may exceed max_len —
+                    # blocks past max_len // bs hold no writable positions,
+                    # so the gather table only needs the addressable prefix
+                    nb = min(len(tbl), self._nb_cols)
+                    tables_arr[r.slot, :nb] = tbl[:nb]
+                rid_arr[t:t + seg.length] = r.rid & 0x7fffffff
+                if seg.is_decode:
+                    from_last[t] = True
+                    slot[t:t + W] = r.slot
+                    p = int(self._pos[r.slot])
+                    # host positions are the worst-case bound; with spec_k > 0
+                    # the program recomputes the true ones from cache_len
+                    pos[t:t + W] = p + np.arange(W)
+                    active[t:t + W] = True
+                    if self.spec_k:
+                        tokens[t + 1:t + W] = seg.draft
+                        verify_idx[r.slot] = np.arange(t, t + W)
+                        sample_at.append((r.rid, r.slot, "verify"))
+                    else:
+                        if tbl is not None and p // bs < len(tbl):
+                            token_dst[t] = tbl[p // bs] * bs + p % bs
+                        sample_at.append((r.rid, r.slot, "decode"))
+                    t += W
+                else:
+                    ln = seg.length
+                    tokens[t:t + ln] = r.prompt[seg.offset:seg.offset + ln]
+                    slot[t:t + ln] = r.slot
+                    qs = np.arange(seg.offset, seg.offset + ln)
+                    pos[t:t + ln] = qs
+                    active[t:t + ln] = True
+                    if tbl is not None and len(tbl):
+                        cov = qs // bs < len(tbl)
+                        token_dst[t:t + ln] = np.where(
+                            cov, tbl[np.minimum(qs // bs, len(tbl) - 1)] * bs
+                            + qs % bs, oob)
+                    if seg.offset + ln == r.prompt_len:
+                        sample_at.append((r.rid, r.slot, "prefill"))
+                    t += ln
+            assert t == packed.tokens, (t, packed.tokens)
+            # single-sample points scatter into ring column 0; non-sample
+            # positions write out of bounds -> dropped.  Verify segments are
+            # NOT sample points: their whole row lands by acceptance
+            sample_slot = np.full((t_total,), self.max_slots, np.int32)
+            t = 0
             for seg in packed.segments:
                 if seg.is_decode:
-                    self.kv.ensure(seg.req.rid,
-                                   int(self._pos[seg.req.slot]) + W)
-
-        t_total = packed.launch_tokens
-        tokens = np.zeros((t_total,), np.int32)
-        slot = np.zeros((t_total,), np.int32)
-        pos = np.zeros((t_total,), np.int32)
-        active = np.zeros((t_total,), bool)
-        # decode positions take the ring's newest accepted token on device
-        # (§10/§13): the host writes a placeholder and never needs the
-        # sampled value
-        from_last = np.zeros((t_total,), bool)
-        # block-table operands (prefix mode): per-token flat scatter target
-        # (OOB = dropped write, covers padding) and per-slot block tables
-        token_dst = np.full((t_total,), oob, np.int64)
-        tables_arr = np.zeros((self.max_slots, self._nb_cols), np.int32)
-        # per-slot verify stream positions (§13); OOB rows (== t_total)
-        # mark slots with no verify segment this iteration
-        verify_idx = np.full((self.max_slots, W), t_total, np.int32)
-        # per-token request id: the stochastic sampler's PRNG identity
-        # (sampling.packed_keys folds (rid, pos) — slot- and
-        # launch-independent); dead under greedy
-        rid_arr = np.zeros((t_total,), np.int32)
-        sample_at: list[tuple[int, int, str]] = []   # (rid, slot, kind)
-        t = 0
-        for seg in packed.segments:
-            r = seg.req
-            tbl = None
-            if self.prefix_caching:
-                tbl = np.asarray(self.kv.table(r.rid), np.int64)
-                # the allocator sizes tables by *predicted* length
-                # (prompt + avg_decode), which may exceed max_len — blocks
-                # past max_len // bs hold no writable positions, so the
-                # gather table only needs the addressable prefix
-                nb = min(len(tbl), self._nb_cols)
-                tables_arr[r.slot, :nb] = tbl[:nb]
-            rid_arr[t:t + seg.length] = r.rid & 0x7fffffff
-            if seg.is_decode:
-                from_last[t] = True
-                slot[t:t + W] = r.slot
-                p = int(self._pos[r.slot])
-                # host positions are the worst-case bound; with spec_k > 0
-                # the program recomputes the true ones from cache_len
-                pos[t:t + W] = p + np.arange(W)
-                active[t:t + W] = True
-                if self.spec_k:
-                    tokens[t + 1:t + W] = seg.draft
-                    verify_idx[r.slot] = np.arange(t, t + W)
-                    sample_at.append((r.rid, r.slot, "verify"))
+                    if not self.spec_k:
+                        sample_slot[t] = seg.req.slot
+                    t += W
                 else:
-                    if tbl is not None and p // bs < len(tbl):
-                        token_dst[t] = tbl[p // bs] * bs + p % bs
-                    sample_at.append((r.rid, r.slot, "decode"))
-                t += W
-            else:
-                ln = seg.length
-                tokens[t:t + ln] = r.prompt[seg.offset:seg.offset + ln]
-                slot[t:t + ln] = r.slot
-                qs = np.arange(seg.offset, seg.offset + ln)
-                pos[t:t + ln] = qs
-                active[t:t + ln] = True
-                if tbl is not None and len(tbl):
-                    cov = qs // bs < len(tbl)
-                    token_dst[t:t + ln] = np.where(
-                        cov, tbl[np.minimum(qs // bs, len(tbl) - 1)] * bs
-                        + qs % bs, oob)
-                if seg.offset + ln == r.prompt_len:
-                    sample_at.append((r.rid, r.slot, "prefill"))
-                t += ln
-        assert t == packed.tokens, (t, packed.tokens)
-        # single-sample points scatter into ring column 0; non-sample
-        # positions write out of bounds -> dropped.  Verify segments are
-        # NOT sample points — their whole row lands via the acceptance path
-        sample_slot = np.full((t_total,), self.max_slots, np.int32)
-        t = 0
-        for seg in packed.segments:
-            if seg.is_decode:
-                if not self.spec_k:
-                    sample_slot[t] = seg.req.slot
-                t += W
-            else:
-                if seg.offset + seg.length == seg.req.prompt_len:
-                    sample_slot[t + seg.length - 1] = seg.req.slot
-                t += seg.length
+                    if seg.offset + seg.length == seg.req.prompt_len:
+                        sample_slot[t + seg.length - 1] = seg.req.slot
+                    t += seg.length
 
-        # iteration's KV-length bucket (DESIGN.md §9): every attended row
-        # must sit below it — the scheduler quantized the max extent up
-        # (host pos is the §13 worst case, so the check stays sufficient)
-        kv_bucket = packed.kv_bucket if packed.kv_bucket is not None \
-            else self.max_len
-        assert not active.any() or int(pos[active].max()) < kv_bucket, \
-            (int(pos[active].max()), kv_bucket)
-        self.stats.kv_bucket_hist[kv_bucket] = \
-            self.stats.kv_bucket_hist.get(kv_bucket, 0) + 1
-        self.stats.packed_attn_kv_rows += packed.launch_tokens * kv_bucket
-        if self.tp > 1:
-            self.stats.tp_collective_bytes += \
-                packed.launch_tokens * self._tp_iter_bytes
+            # iteration's KV-length bucket (DESIGN.md §9): every attended row
+            # must sit below it — the scheduler quantized the max extent up
+            # (host pos is the §13 worst case, so the check stays sufficient)
+            kv_bucket = packed.kv_bucket if packed.kv_bucket is not None \
+                else self.max_len
+            assert not active.any() or int(pos[active].max()) < kv_bucket, \
+                (int(pos[active].max()), kv_bucket)
+            self.stats.kv_bucket_hist[kv_bucket] = \
+                self.stats.kv_bucket_hist.get(kv_bucket, 0) + 1
+            self.stats.packed_attn_kv_rows += packed.launch_tokens * kv_bucket
+            if self.tp > 1:
+                self.stats.tp_collective_bytes += \
+                    packed.launch_tokens * self._tp_iter_bytes
 
-        tok_in = jnp.asarray(tokens[None])
-        if self.cfg.frontend == "audio":
-            tok_in = jnp.repeat(tok_in[..., None], self.cfg.num_codebooks,
-                                axis=-1)
-        # launch-side bookkeeping BEFORE dispatch: the scheduler's next plan
-        # may be formed while this iteration is still on device (§10)
-        self.scheduler.mark_launched(plan)
-        n_decode = 0
-        for seg in packed.segments:
-            if seg.is_decode:
-                self._pos[seg.req.slot] += W
-                n_decode += 1
+            tok_in = jnp.asarray(tokens[None])
+            if self.cfg.frontend == "audio":
+                tok_in = jnp.repeat(tok_in[..., None], self.cfg.num_codebooks,
+                                    axis=-1)
+            # launch-side bookkeeping BEFORE dispatch: the scheduler's next
+            # plan may be formed while this iteration is still on device (§10)
+            self.scheduler.mark_launched(plan)
+            n_decode = 0
+            for seg in packed.segments:
+                if seg.is_decode:
+                    self._pos[seg.req.slot] += W
+                    n_decode += 1
+                else:
+                    self._pos[seg.req.slot] = seg.offset + seg.length
+            # count the guaranteed base sample per decode/verify segment here;
+            # accepted drafts are added at retire time (device truth)
+            self.stats.decode_tokens += n_decode
+            self.stats.prefill_tokens += packed.tokens - n_decode * W
+            self.stats.prefill_model_tokens += packed.tokens - n_decode * W
+            self.stats.packed_pad_tokens += packed.padding
+            if self._cache_kv_dtype is not None:
+                # cache bytes this launch did NOT write vs the native-dtype
+                # layout: every real token scatters one quantized row per
+                # attention layer (DESIGN.md §15)
+                self.stats.kv_quant_bytes_saved += packed.tokens * \
+                    (self._kv_bytes_native - self.kv.bytes_per_token)
+            if self.prefix_caching:
+                dst_op = jnp.asarray(token_dst.astype(np.int32))
+                tbl_op = jnp.asarray(tables_arr)
+                # drain queued copy-on-write block copies BEFORE the dispatch:
+                # cache donation chains each copy in front of the forward pass
+                # on device, with no host sync and no extra model dispatch
+                for c_src, c_dst in self.kv.take_pending_copies():
+                    self.cache = self._cow_step(self.cache, jnp.int32(c_src),
+                                                jnp.int32(c_dst))
             else:
-                self._pos[seg.req.slot] = seg.offset + seg.length
-        # count the guaranteed base sample per decode/verify segment here;
-        # accepted drafts are added at retire time (device truth)
-        self.stats.decode_tokens += n_decode
-        self.stats.prefill_tokens += packed.tokens - n_decode * W
-        self.stats.prefill_model_tokens += packed.tokens - n_decode * W
-        self.stats.packed_pad_tokens += packed.padding
-        if self._cache_kv_dtype is not None:
-            # cache bytes this launch did NOT write vs the native-dtype
-            # layout: every real token scatters one quantized row per
-            # attention layer (DESIGN.md §15)
-            self.stats.kv_quant_bytes_saved += packed.tokens * \
-                (self._kv_bytes_native - self.kv.bytes_per_token)
-        if self.prefix_caching:
-            dst_op = jnp.asarray(token_dst.astype(np.int32))
-            tbl_op = jnp.asarray(tables_arr)
-            # drain queued copy-on-write block copies BEFORE the dispatch:
-            # cache donation chains each copy in front of the forward pass
-            # on device, with no host sync and no extra model dispatch
-            for c_src, c_dst in self.kv.take_pending_copies():
-                self.cache = self._cow_step(self.cache, jnp.int32(c_src),
-                                            jnp.int32(c_dst))
-        else:
-            dst_op, tbl_op = self._dummy_dst, self._dummy_blk
+                dst_op, tbl_op = self._dummy_dst, self._dummy_blk
         t_disp = time.perf_counter()
         self.stats.host_time += t_disp - t_host
-        payload, self.cache, self.cache_len, self.last_token, \
-            self.accept_len = self._packed_step(
-                self.params, self.cache, tok_in, jnp.asarray(slot),
-                jnp.asarray(pos), jnp.asarray(active), self.cache_len,
-                jnp.asarray(reset), self.last_token, self.accept_len,
-                jnp.asarray(from_last), jnp.asarray(sample_slot),
-                jnp.asarray(verify_idx), jnp.asarray(rid_arr),
-                dst_op, tbl_op, kv_bucket)
+        it = self.stats.iterations
+        _dispatching.at = (self.stats, (t_total, kv_bucket))
+        try:
+            with spans.span(spans.DISPATCH, iteration=it, tokens=t_total,
+                            kv_bucket=kv_bucket):
+                payload, self.cache, self.cache_len, self.last_token, \
+                    self.accept_len = self._packed_step(
+                        self.params, self.cache, tok_in, jnp.asarray(slot),
+                        jnp.asarray(pos), jnp.asarray(active),
+                        self.cache_len, jnp.asarray(reset), self.last_token,
+                        self.accept_len, jnp.asarray(from_last),
+                        jnp.asarray(sample_slot), jnp.asarray(verify_idx),
+                        jnp.asarray(rid_arr), dst_op, tbl_op, kv_bucket)
+        finally:
+            _dispatching.at = None
         self.stats.dispatch_time += time.perf_counter() - t_disp
         self.stats.model_dispatches += 1
-        return _InFlight(plan=plan, sample_at=sample_at, tokens=payload)
+        return _InFlight(plan=plan, sample_at=sample_at, tokens=payload,
+                         iteration=it)
 
     # ---- legacy iteration: decode dispatch + one dispatch per chunk ---------
     def _step_legacy(self, plan: BatchPlan) -> dict[int, int]:
@@ -1150,6 +1192,8 @@ class ServeEngine:
                 assert self.slot_free, "scheduler admitted beyond slot capacity"
                 r.slot = self.slot_free.pop()
                 self._pos[r.slot] = 0
+                if r.scheduled_at is None:
+                    r.scheduled_at = self._clock()
                 if self.prefill_mode == "incremental":
                     self.cache = self._reset_step(
                         self.cache, self._slot_init, jnp.int32(r.slot))

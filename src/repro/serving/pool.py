@@ -449,14 +449,16 @@ class ReplicaPool:
                    duration: Optional[float] = None) -> dict[int, Request]:
         """Wall-clock driver for benchmarks/serve: submit request ``k`` at
         ``t0 + offsets[k]``, tick when there is work, sleep (never
-        busy-wait) when idle before the next arrival."""
+        busy-wait) when idle before the next arrival.  A request's
+        ``arrival`` is the time it was due, so a tick that stalls past it
+        counts in its latency."""
         assert len(reqs) == len(offsets)
         t0 = self.clock()
         i = 0
         while True:
             now = self.clock() - t0
             while i < len(reqs) and offsets[i] <= now:
-                reqs[i].arrival = self.clock()
+                reqs[i].arrival = t0 + offsets[i]
                 self.submit(reqs[i])
                 i += 1
             if i >= len(reqs) and self.outstanding() == 0:

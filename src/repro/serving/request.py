@@ -31,6 +31,9 @@ class Request:
     prefill_done: int = 0              # tokens prefilled so far (chunked)
     output: list[int] = dataclasses.field(default_factory=list)
     slot: int = -1                     # engine cache slot while active
+    # engine clock when the request first got a slot, so its first chunk
+    # launched: queue wait is ``scheduled_at - arrival``
+    scheduled_at: Optional[float] = None
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
     # async EOS (paper §5.3): EOS seen at iter i is acted on at iter i+1

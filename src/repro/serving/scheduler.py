@@ -38,6 +38,7 @@ from typing import Optional
 
 from repro.core.nanobatch import (NanoBatchPlan, nano_batch_sizes_for,
                                   packed_segment_order)
+from repro.serving import spans
 from repro.serving.kvcache import PagedKVManager
 from repro.serving.request import Request, State
 
@@ -223,6 +224,7 @@ class GlobalBatchScheduler:
                 and len(r.output) + r.inflight < r.max_new_tokens
                 and not (r.pending_eos and r.inflight > 0))
 
+    @spans.spanned(spans.PLAN)
     def plan(self) -> Optional[BatchPlan]:
         self._admit()
         decode = [r for r in self.active if self._decodable(r)]
@@ -329,6 +331,7 @@ class GlobalBatchScheduler:
             prop += [pad] * (self.spec_k - len(prop))
         return tuple(int(t) for t in prop)
 
+    @spans.spanned(spans.PACK)
     def pack(self, plan: BatchPlan, *, nano: int = 2) -> PackedPlan:
         """Lay one iteration's decode tokens + prefill chunks out as a
         token-packed stream: segments ordered by the nano-batch interleave
@@ -362,6 +365,7 @@ class GlobalBatchScheduler:
                           kv_needed=kv_needed)
 
     # ---- post-iteration bookkeeping -------------------------------------------
+    @spans.spanned(spans.COMMIT)
     def commit(self, plan: BatchPlan, sampled, now: float) -> list[Request]:
         """Apply iteration results.  ``sampled``: rid -> next token id, or
         — for a §13 verify segment — the *accepted* token list (1 to

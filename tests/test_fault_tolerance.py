@@ -349,3 +349,19 @@ def test_pool_snapshot_counter_schema(toy):
     esnap = pool.router.replicas[1].engine.stats.snapshot()
     assert esnap["evacuated_requests"] == pool.stats.redispatched_requests
     assert esnap["evacuated_tokens"] == pool.stats.redispatched_tokens
+
+
+def test_run_online_keeps_due_time_through_a_stalled_tick(toy):
+    """A request due while a tick runs (here a whole virtual second) keeps
+    the time it was due as ``arrival``, so the stall counts in its latency;
+    it is scheduled, on the engine's (the pool's) clock, only after."""
+    cfg, params = toy
+    pool = ReplicaPool([ServeEngine(cfg, params, _ecfg(1))],
+                       PoolConfig(replicas=1), virtual_dt=1.0)
+    reqs = [Request(rid=i, prompt=list(range(5 + i, 15 + i)),
+                    max_new_tokens=8) for i in range(2)]
+    done = pool.run_online(reqs, [0.0, 0.25])
+    assert done[1].arrival == 0.25
+    assert done[0].scheduled_at == 1.0
+    assert done[1].scheduled_at >= 2.0
+    assert done[1].first_token_at >= done[1].scheduled_at

@@ -9,6 +9,7 @@ from _hyp import given, settings, st  # optional-hypothesis shim
 
 from repro.configs import get_config
 from repro.models import model
+from repro.serving.config import EngineConfig
 from repro.serving.engine import ServeEngine
 from repro.serving.kvcache import PagedKVManager
 from repro.serving.request import Request
@@ -163,3 +164,24 @@ def test_scheduler_admission_respects_capacity():
     assert plan is not None
     assert sched.n_active < 10           # capacity-bounded admission
     assert kv.pages_used <= kv.stats.device_pages_total
+
+
+def test_compile_hist_counts_each_launch_key_once(toy):
+    """A packed launch at a new (launch tokens, KV bucket) compiles once and
+    is charged to that key; a second launch at the same key adds nothing,
+    and throughput leaves the compile's seconds out of the wall time."""
+    cfg, params = toy
+    eng = ServeEngine(cfg, params, EngineConfig(
+        max_slots=2, max_len=32, discrete_sizes=(8,), avg_decode_len=4.0))
+    eng.submit(Request(rid=0, prompt=[1, 2, 3], max_new_tokens=1))
+    eng.run()
+    s = eng.stats
+    assert s.iterations == 1 and s.compiles == 1
+    (key, n), = s.compile_hist.items()
+    assert n == 1 and key[1] in s.kv_bucket_hist and s.compile_time > 0
+    assert s.throughput == pytest.approx(
+        s.total_tokens / (s.wall_time - s.compile_time))
+    eng.submit(Request(rid=1, prompt=[4, 5, 6], max_new_tokens=1))
+    eng.run()
+    assert s.iterations == 2 and s.compiles == 1
+    assert s.compile_hist == {key: 1}
