@@ -2,7 +2,11 @@
 
 Covers the tentpole invariants:
   * Pallas (interpret=True) packed attention == the XLA ref across GQA and
-    absorbed-MLA shapes (incl. ``d_v != d_qk``), f32 and bf16;
+    absorbed-MLA shapes (incl. ``d_v != d_qk``), f32 and bf16, on random
+    packings and on engine-shaped launches that exercise the query-tile
+    plan (``LAUNCHES``);
+  * ``kv_blocks_fetched`` counts the KV block DMAs the clamped index maps
+    issue;
   * ``ops.packed_attention`` dispatches ``impl`` for real — the MLA
     ``d_v != d_qk`` case runs the Pallas kernel, no silent ref downgrade;
   * kv-bucket slicing is exact at and around bucket boundaries, in the ref,
@@ -39,13 +43,69 @@ def _tol(dtype):
         else dict(rtol=1e-5, atol=2e-5)
 
 
+# engine-shaped launches, new cases of the parity tests below where ``t``
+# names one: name -> (packing, tokens per query tile, block-table block size)
+LAUNCHES = {
+    # decodes in slots 1.., a prefill chunk in slot 0 whose lengths rise
+    # with position, then a pad tail in slot 0 with length 1
+    "engine-bq8": ("engine", 8, None),
+    "shuffled-bq8": ("shuffled", 8, None),      # the same, packed at random
+    "engine-bq64": ("engine", 64, None),        # T < Bq
+    "runs-bq4": ("runs", 4, None),              # worst case: T one-token runs
+    "engine-bq8-bt8": ("engine", 8, 8),         # block-table mode
+    "shuffled-bq8-bt8": ("shuffled", 8, 8),
+}
+
+
+def _launch(layout, n, s):
+    """(token_slot, lengths) packed as the engine packs an iteration."""
+    if layout == "runs":
+        return np.arange(n), RNG.integers(1, s + 1, size=n)
+    c = s // 4
+    off = int(RNG.integers(0, s - c + 1))
+    slot = np.r_[np.arange(1, n), np.zeros(c + 8, int)]
+    lens = np.r_[RNG.integers(s // 2, s + 1, size=n - 1),
+                 off + np.arange(1, c + 1), np.ones(8, int)]
+    if layout == "shuffled":
+        order = RNG.permutation(slot.size)
+        slot, lens = slot[order], lens[order]
+    return slot, lens
+
+
 def _case(t, n, s, h, kv, d_qk, d_v, dtype):
+    if isinstance(t, str):
+        slot, lens = _launch(LAUNCHES[t][0], n, s)
+        t = slot.size
+    else:
+        slot = RNG.integers(0, n, size=t)
+        lens = RNG.integers(1, s + 1, size=t)
     q = jnp.asarray(RNG.normal(size=(t, h, d_qk)), dtype)
     k = jnp.asarray(RNG.normal(size=(n, s, kv, d_qk)), dtype)
     v = jnp.asarray(RNG.normal(size=(n, s, kv, d_v)), dtype)
-    slot = jnp.asarray(RNG.integers(0, n, size=t), jnp.int32)
-    lens = jnp.asarray(RNG.integers(1, s + 1, size=t), jnp.int32)
-    return q, k, v, slot, lens
+    return (q, k, v, jnp.asarray(slot, jnp.int32),
+            jnp.asarray(lens, jnp.int32))
+
+
+def _tiling(t):
+    """Kernel keywords of launch ``t``: tokens per tile; for a block-table
+    launch, the table (applied to the caches by ``_to_blocks``)."""
+    if not isinstance(t, str):
+        return {}, None
+    _, bq, bs = LAUNCHES[t]
+    return {"block_q": bq}, bs
+
+
+def _to_blocks(n, s, bs, *caches):
+    """Scatter each slot's logical blocks of ``bs`` rows into a permuted
+    physical row space; returns the table and the physical caches."""
+    nb = s // bs
+    perm = RNG.permutation(n * nb)
+
+    def phys(x):
+        flat = x.reshape(n * nb, bs, *x.shape[2:])
+        return jnp.zeros_like(flat).at[perm].set(flat).reshape(x.shape)
+    return (jnp.asarray(perm.reshape(n, nb), jnp.int32),
+            *[phys(x) for x in caches])
 
 
 # ---------------------------------------------------------------------------
@@ -56,11 +116,22 @@ def _case(t, n, s, h, kv, d_qk, d_v, dtype):
     (10, 3, 64, 4, 2, 32, 32),       # GQA
     (7, 2, 48, 8, 8, 16, 16),        # MHA
     (5, 4, 40, 4, 1, 16, 16),        # MQA, ragged S
+    ("engine-bq8", 3, 64, 4, 2, 32, 32),
+    ("shuffled-bq8", 3, 64, 4, 2, 32, 32),
+    ("engine-bq64", 3, 64, 4, 2, 32, 32),
+    ("runs-bq4", 6, 40, 4, 2, 16, 16),          # ragged S
+    ("engine-bq8", 3, 40, 4, 1, 24, 16),        # absorbed MLA, ragged S
+    ("engine-bq8-bt8", 3, 64, 4, 2, 32, 32),
+    ("shuffled-bq8-bt8", 4, 48, 4, 1, 24, 16),  # MLA in block-table mode
 ])
 def test_packed_attention_parity_gqa(t, n, s, h, kv, d_qk, d_v, dtype):
     q, k, v, slot, lens = _case(t, n, s, h, kv, d_qk, d_v, dtype)
-    out = pa.packed_attention(q, k, v, slot, lens, block_k=16, interpret=True)
+    kw, bs = _tiling(t)
     want = ref.packed_attention_ref(q, k, v, slot, lens)
+    if bs is not None:
+        kw["block_tables"], k, v = _to_blocks(n, s, bs, k, v)
+    out = pa.packed_attention(q, k, v, slot, lens, block_k=16, interpret=True,
+                              **kw)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
 
@@ -115,6 +186,9 @@ def _quantize(k, v):
 @pytest.mark.parametrize("t,n,s,h,kv,d_qk,d_v", [
     (10, 3, 64, 4, 2, 32, 32),       # GQA
     (6, 3, 48, 4, 1, 24, 16),        # absorbed MLA: d_v != d_qk
+    ("engine-bq8", 3, 64, 4, 2, 32, 32),
+    ("shuffled-bq8-bt8", 3, 64, 4, 2, 32, 32),  # int8 + block table
+    ("runs-bq4", 6, 48, 4, 1, 24, 16),          # absorbed MLA
 ])
 def test_packed_attention_int8_parity(t, n, s, h, kv, d_qk, d_v):
     """Pallas kernel with int8 k/v + f32 scale tiles == ref dequant path
@@ -123,10 +197,16 @@ def test_packed_attention_int8_parity(t, n, s, h, kv, d_qk, d_v):
     q, k, v, slot, lens = _case(t, n, s, h, kv, d_qk, d_v, jnp.float32)
     kq, ks, vq, vs = _quantize(k, v)
     scale = d_qk ** -0.5
-    out = pa.packed_attention(q, kq, vq, slot, lens, k_scale=ks, v_scale=vs,
-                              logit_scale=scale, block_k=16, interpret=True)
     want = ref.packed_attention_ref(q, kq, vq, slot, lens, k_scale=ks,
                                     v_scale=vs, logit_scale=scale)
+    kw, bs = _tiling(t)
+    caches = (kq, vq, ks, vs)
+    if bs is not None:
+        kw["block_tables"], *caches = _to_blocks(n, s, bs, *caches)
+    kq_p, vq_p, ks_p, vs_p = caches
+    out = pa.packed_attention(q, kq_p, vq_p, slot, lens, k_scale=ks_p,
+                              v_scale=vs_p, logit_scale=scale, block_k=16,
+                              interpret=True, **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-5, atol=2e-5)
     exact = ref.packed_attention_ref(q, k, v, slot, lens, logit_scale=scale)
@@ -214,6 +294,68 @@ def test_packed_attention_no_cache_pad(monkeypatch):
     q, k, v, slot, lens = _case(9, 3, 41, 4, 2, 16, 16, jnp.float32)
     pa.packed_attention(q, k, v, slot, lens, block_k=16, interpret=True)
     assert calls == []
+
+
+# ---------------------------------------------------------------------------
+# the tile plan's KV block DMAs: the numpy count == a host replay of the
+# kernel's own tile plan and clamped index map
+# ---------------------------------------------------------------------------
+def _replayed_kv_dmas(slot, lens, *, n, kv, group, bucket, block_q):
+    """Consecutive distinct K block indices over the grid, in grid order,
+    from ``_tile_plan`` and ``_kv_index`` as the kernel runs them."""
+    t = len(slot)
+    block_k = min(pa.DEFAULT_BLOCK_K, max(8, bucket))
+    bq = pa.tile_tokens(t, group, block_q)
+    n_tiles = pa.num_tiles(t, bq, n)
+    *_, tile_slot, tile_len, tile_hi = pa._tile_plan(
+        jnp.asarray(slot, jnp.int32), jnp.asarray(lens, jnp.int32),
+        n_slots=n, bq=bq, n_tiles=n_tiles, block_k=block_k)
+    tables = [np.asarray(x) for x in (tile_slot, tile_len, tile_hi)]
+    prev, dmas = None, 0
+    for i in range(n_tiles):
+        for head in range(kv):
+            for sb in range(-(-bucket // block_k)):
+                idx = tuple(int(x) for x in pa._kv_index(
+                    i, head, sb, *tables, kv_heads=kv))
+                dmas += idx != prev
+                prev = idx
+    return dmas
+
+
+def _cell_launch():
+    """The Qwen3-4B offline cell's typical launch: 7 decodes, a 128-token
+    chunk and 121 pads in slot 0, KV bucket 2048."""
+    slot = np.r_[np.arange(1, 8), np.zeros(128 + 121, int)]
+    lens = np.r_[RNG.integers(1025, 2049, size=7),
+                 1500 + np.arange(1, 129), np.ones(121, int)]
+    return slot, lens
+
+
+@pytest.mark.parametrize("launch,n,s,kv,group,block_q", [
+    ("engine", 3, 64, 2, 4, 8),
+    ("shuffled", 5, 96, 2, 2, 4),
+    ("runs", 6, 64, 1, 8, 4),        # one kv head: tiles share blocks
+    ("engine", 4, 64, 2, 4, 64),     # T < Bq
+    ("cell", 8, 2048, 8, 4, pa.DEFAULT_BLOCK_Q),
+])
+def test_kv_blocks_fetched_matches_index_map_replay(launch, n, s, kv, group,
+                                                    block_q):
+    slot, lens = _cell_launch() if launch == "cell" else _launch(launch, n, s)
+    want = _replayed_kv_dmas(slot, lens, n=n, kv=kv, group=group, bucket=s,
+                             block_q=block_q)
+    got = pa.kv_blocks_fetched(slot, lens, n_slots=n, kv_heads=kv,
+                               group=group, kv_bucket=s, block_q=block_q)
+    assert got == want
+
+
+def test_kv_blocks_fetched_cell_launch_10x_below_per_token():
+    slot, lens = _cell_launch()
+    tiled = pa.kv_blocks_fetched(slot, lens, n_slots=8, kv_heads=8, group=4,
+                                 kv_bucket=2048)
+    per_token = pa.kv_blocks_fetched_per_token(256, kv_heads=8,
+                                               kv_bucket=2048)
+    assert per_token == 256 * 8 * 8
+    assert 10 * tiled <= per_token, (tiled, per_token)
 
 
 # ---------------------------------------------------------------------------

@@ -71,6 +71,8 @@ KERNELS = [
     ("block-table-int8", 256, 32, 8, 128, 128, 1024, True, 16),
     # deepseek-v2 absorbed MLA: 128 heads over one latent, d_v != d_qk
     ("mla-absorbed", 256, 128, 1, 576, 512, 1024, False, None),
+    # the offline cell's mixed launch: query tiles at the widest bucket
+    ("bf16-engine-T256-kv2048", 256, 32, 8, 128, 128, 2048, False, None),
 ]
 
 
