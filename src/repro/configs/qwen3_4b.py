@@ -1,4 +1,4 @@
-"""Qwen3-4B — dense, qk_norm, GQA [hf:Qwen/Qwen3-8B; hf].
+"""Qwen3-4B — dense, qk_norm, GQA [hf:Qwen/Qwen3-4B; hf].
 
 36L d_model=2560 32H (GQA kv=8) d_ff=9728 vocab=151936.
 """

@@ -1,6 +1,7 @@
 """Qwen3-8B — dense, qk_norm, GQA [hf:Qwen/Qwen3-8B; hf].
 
-36L d_model=4096 32H (GQA kv=8) d_ff=12288 vocab=151936.
+36L d_model=4096 32H (GQA kv=8) d_ff=12288 vocab=151936, rms_norm_eps 1e-6,
+untied LM head.
 """
 from repro.configs.base import LayerSpec, ModelConfig, register
 
@@ -17,5 +18,6 @@ CONFIG = register(ModelConfig(
     block_pattern=(LayerSpec(),),
     qk_norm=True,
     rope_theta=1_000_000.0,
+    norm_eps=1e-6,
     citation="hf:Qwen/Qwen3-8B",
 ))

@@ -415,32 +415,38 @@ def validate_tp(cfg: ModelConfig, tp: int) -> None:
 # ---------------------------------------------------------------------------
 def collective_bytes_per_iter(cfg: ModelConfig, t: int, tp: int,
                               itemsize: int) -> int:
-    """Rough wire-byte model of one packed iteration's TP collectives: each
-    row-parallel all-reduce moves ~``2(p-1)/p × payload`` per shard (ring).
-    Counts the per-layer output projections, the MoE combine psum, and the
-    mamba dt/B/C + mLSTM gate/norm psums inside the token scans.  A model,
-    not a measurement — reported per iteration by the benchmarks."""
+    """Wire-byte model of one packed iteration's TP collectives: the bytes
+    each shard receives.  A row-parallel reduction (``row_parallel``, the
+    per-layer output projections) is ``matmul_allreduce``: a ring
+    reduce-scatter of float32 partial sums, then a ring all-gather in the
+    activation dtype, so ``(p-1)/p × payload × (4 + itemsize)``.  A plain
+    ``psum`` (the MoE combine, mamba dt/B/C, the mLSTM gates and norm inside
+    the token scans) is a ring all-reduce, ``2(p-1)/p × payload`` of its
+    dtype.  A model, not a measurement — reported per iteration by the
+    benchmarks."""
     if tp <= 1:
         return 0
     d = cfg.d_model
-    payload = 0
+    rows = psums = 0
     for spec in cfg.layer_specs():
         if spec.mixer == ATTN:
-            payload += t * d                       # wo all-reduce
+            rows += t * d                          # wo
         elif spec.mixer == MAMBA:
             from repro.models.ssm import _dims
             _, dt_rank, n = _dims(cfg)
-            payload += t * d + t * (dt_rank + 2 * n)   # w_out AR + dt/B/C
+            rows += t * d                          # w_out
+            psums += t * (dt_rank + 2 * n) * itemsize      # dt/B/C
         elif spec.mixer == MLSTM:
-            payload += t * d + t * (2 * cfg.n_heads + 1)  # w_down + gates+norm
+            rows += t * d                          # w_down
+            psums += t * (2 * cfg.n_heads + 1) * 4         # f32 gates+norm
         elif spec.mixer == SLSTM:
-            payload += t * d                       # w_ffn_down all-reduce
+            rows += t * d                          # w_ffn_down
         if spec.ffn == FFN_DENSE:
-            payload += t * d
+            rows += t * d
         elif spec.ffn in (FFN_MOE, FFN_MOE_DENSE):
-            payload += t * d                       # combine psum
+            psums += t * d * itemsize              # combine psum
             if cfg.moe.num_shared_experts:
-                payload += t * d
+                rows += t * d
             if spec.ffn == FFN_MOE_DENSE:
-                payload += t * d
-    return int(2 * (tp - 1) / tp * payload * itemsize)
+                rows += t * d
+    return int((tp - 1) / tp * (rows * (4 + itemsize) + 2 * psums))

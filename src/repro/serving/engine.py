@@ -1140,7 +1140,8 @@ class ServeEngine:
         _dispatching.at = (self.stats, (t_total, kv_bucket))
         try:
             with spans.span(spans.DISPATCH, iteration=it, tokens=t_total,
-                            kv_bucket=kv_bucket):
+                            kv_bucket=kv_bucket, tp=self.tp,
+                            nano=spans.groups(packed.nano.sizes)):
                 payload, self.cache, self.cache_len, self.last_token, \
                     self.accept_len = self._packed_step(
                         self.params, self.cache, tok_in, jnp.asarray(slot),

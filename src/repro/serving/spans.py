@@ -4,7 +4,7 @@ Each span is a ``jax.profiler.TraceAnnotation``: it lands on the same
 timeline as the device's operations when a profiler session is running, and
 costs about a microsecond when none is.  So the spans are always on; there
 is no other recorder.  Keyword metadata (``iteration``, ``tokens``,
-``kv_bucket``) becomes the event's stats in the trace.
+``kv_bucket``, ``tp``, ``nano``) becomes the event's stats in the trace.
 
 The names say where each span sits on the packed path:
 
@@ -17,7 +17,10 @@ The names say where each span sits on the packed path:
 ``engine.dispatch`` the jitted packed step, with the copies of the operands
                     in its argument list; metadata ``iteration`` (the
                     launch's ``stats.iterations``), ``tokens`` (launch
-                    length), ``kv_bucket``
+                    length), ``kv_bucket``, ``tp`` (devices the step is
+                    split over) and ``nano`` (the launch's nano-batch
+                    group sizes, ``groups``: under TP each group's
+                    row-parallel reductions are collectives of their own)
 ``engine.retire``   ``ServeEngine._retire_oldest``: the fetch, the payload
                     decode, the commit and ``_finalize``
 ``engine.fetch``    ``ServeEngine._fetch``: the wait for the payload and its
@@ -44,9 +47,16 @@ FETCH = "engine.fetch"
 ALL = (PLAN, PACK, COMMIT, OPERANDS, DISPATCH, RETIRE, FETCH)
 
 
-def span(name: str, **meta: int) -> jax.profiler.TraceAnnotation:
-    """A host span ``name`` with integer metadata, as a context manager."""
+def span(name: str, **meta: int | str) -> jax.profiler.TraceAnnotation:
+    """A host span ``name`` with integer or string metadata, as a context
+    manager."""
     return jax.profiler.TraceAnnotation(name, **meta)
+
+
+def groups(sizes) -> str:
+    """Nano-batch group sizes as one metadata value, ``"128+128"`` (the
+    trace's metadata encoding splits values at commas)."""
+    return "+".join(str(int(s)) for s in sizes)
 
 
 def spanned(name: str):

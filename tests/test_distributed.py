@@ -99,6 +99,78 @@ def test_tp_engine_token_equivalence_subprocess():
     assert "OK" in out
 
 
+def _wire_bytes(jaxpr, p: int) -> int:
+    """Bytes one shard receives through the collectives of a traced
+    program: a ``ppermute`` its operand, an ``all_gather`` the (p-1)/p of
+    its result that other shards hold, a ``psum`` a ring all-reduce;
+    loop bodies count once per trip."""
+    from jax.extend import core as jcore
+    total = 0
+    for eqn in jaxpr.eqns:
+        trips = eqn.params["length"] if eqn.primitive.name == "scan" else 1
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    total += trips * _wire_bytes(sub, p)
+        ins = sum(a.aval.size * a.aval.dtype.itemsize for a in eqn.invars
+                  if hasattr(a.aval, "size"))
+        if eqn.primitive.name == "ppermute":
+            total += ins
+        elif eqn.primitive.name == "all_gather":
+            total += sum(a.aval.size * a.aval.dtype.itemsize
+                         for a in eqn.outvars) * (p - 1) // p
+        elif eqn.primitive.name == "psum":
+            total += 2 * ins * (p - 1) // p
+    return total
+
+
+# (arch, layers kept): whole periods of each layer pattern, every mixer
+# family's reduction points (GQA, MLA+MoE, Mamba+attention+MoE, mLSTM+sLSTM)
+WIRE_CASES = [("qwen3-8b", 2), ("deepseek-v2-236b", 2),
+              ("jamba-1.5-large-398b", 8), ("xlstm-1.3b", 2)]
+
+
+@pytest.mark.parametrize("arch,layers", WIRE_CASES,
+                         ids=[a for a, _ in WIRE_CASES])
+def test_collective_bytes_model_matches_the_traced_step(arch, layers):
+    """``tp.collective_bytes_per_iter`` (``EngineStats.tp_collective_bytes``)
+    equals the collectives of the TP packed forward as traced at published
+    widths, over an abstract four-way mesh (no devices): at Qwen3-8B's
+    T=256 each layer's two row-parallel reductions, in two nano groups, are
+    three float32 (128, 1024) ring hops and one bf16 all-gather each."""
+    import dataclasses
+
+    from jax.sharding import AbstractMesh, PartitionSpec as P
+
+    from repro.distributed import tp
+
+    cfg = dataclasses.replace(get_config(arch), n_layers=layers)
+    t, p = 256, 4
+
+    def body(params, cache, tok, slot, pos, act):
+        return model.forward_packed(cfg, params, tok, cache, slot, pos, pos,
+                                    act, kv_bucket=512)[0][0]
+    fn = tp.shard_map_packed(
+        body, AbstractMesh((p,), ("model",)), 2,
+        (tp.param_pspecs_tp(cfg), tp.cache_pspecs_tp(cfg)) + (P(),) * 4,
+        P())
+    vec = jax.ShapeDtypeStruct((t,), jnp.int32)
+    traced = jax.make_jaxpr(fn)(
+        jax.eval_shape(lambda: model.init(cfg, jax.random.PRNGKey(0))),
+        jax.eval_shape(lambda: model.init_cache(cfg, 1, 8, 512)),
+        jax.ShapeDtypeStruct((1, t), jnp.int32), vec, vec,
+        jax.ShapeDtypeStruct((t,), bool))
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    assert _wire_bytes(traced.jaxpr, p) == \
+        tp.collective_bytes_per_iter(cfg, t, p, itemsize)
+    if arch == "qwen3-8b":
+        full = dataclasses.replace(cfg, n_layers=36)
+        assert tp.collective_bytes_per_iter(full, t, p, itemsize) == \
+            36 * 2 * 2 * 3 * 128 * 1024 * (4 + 2)
+
+
 @pytest.mark.parametrize("arch", ["tiny-toy", "xlstm-1.3b",
                                   "jamba-1.5-large-398b"])
 def test_init_params_tp_matches_host_sharding(arch):

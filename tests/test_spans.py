@@ -68,6 +68,22 @@ def test_one_dispatch_and_one_fetch_per_iteration(traced):
             assert s.meta["kv_bucket"] > 0
 
 
+def test_dispatch_names_tp_and_nano_groups(traced):
+    """Each launch says how many devices its step is split over and its
+    nano-batch groups, which add up to the launch length."""
+    _, _, sp = spans.read(traced[0])
+    seen = set()
+    for s in sp:
+        if s.name == spans.DISPATCH:
+            assert s.meta["tp"] == 1
+            groups = [int(g) for g in str(s.meta["nano"]).split("+")]
+            assert sum(groups) == s.meta["tokens"]
+            seen.add(len(groups))
+    # the 16-token launches split in two, the shorter ones do not
+    assert seen == {1, 2}
+    assert program_spans.groups((128, 120)) == "128+120"
+
+
 def test_fetch_and_commit_nest_inside_retire(traced):
     _, _, sp = spans.read(traced[0])
     retire = [s for s in sp if s.name == spans.RETIRE]
