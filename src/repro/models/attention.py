@@ -173,16 +173,39 @@ def gqa_prefill_chunk(cfg: ModelConfig, p: dict, x: jax.Array,
     return y, {"k": k_cache, "v": v_cache}
 
 
-def _flat_scatter(cache: jax.Array, new: jax.Array,
-                  token_dst: jax.Array) -> jax.Array:
-    """Block-table scatter (DESIGN.md §12): write each token's row at its
-    *physical* flat row id (block_id · block_size + offset), computed on the
-    host from the request's block table.  Padding rows carry ``N·S`` (out of
-    bounds → dropped).  The leaf keeps its (N, S, ...) shape."""
-    n, s = cache.shape[:2]
-    flat = cache.reshape((n * s,) + cache.shape[2:])
-    flat = flat.at[token_dst].set(new.astype(cache.dtype), mode="drop")
-    return flat.reshape(cache.shape)
+def _write_and_view(cache: dict, layer: jax.Array, new_rows: dict,
+                    token_slot: jax.Array, token_wpos: jax.Array,
+                    kv_bucket: Optional[int], token_dst, block_tables):
+    """Scatter one layer's T new rows per leaf into the group's stacked
+    cache (L, N_slots, S, ...), carried through the layer loop (DESIGN.md
+    §8): at ``(layer, slot, wpos)``, or in block-table mode (DESIGN.md §12)
+    at ``(layer, token_dst)`` of the (L, N·S, ...) view.  Padding rows
+    carry an out-of-bounds ``wpos``/``dst`` and are dropped; only the T
+    rows move, the stack is updated in place.  Then cut the rows attention
+    reads from layer ``layer``: the first ``kv_bucket`` rows of each slot,
+    or in block-table mode the layer's whole storage (any block may be in
+    a slot's table).  Returns (new stacked cache, per-layer views)."""
+    new_cache, views = {}, {}
+    for name, val in new_rows.items():
+        stack = cache[name]
+        n_layers, n, s = stack.shape[:3]
+        val = val.astype(stack.dtype)
+        if block_tables is None:
+            stack = stack.at[layer, token_slot, token_wpos].set(
+                val, mode="drop")
+            rows = s if kv_bucket is None else min(kv_bucket, s)
+        else:
+            flat = stack.reshape((n_layers, n * s) + stack.shape[3:])
+            stack = flat.at[layer, token_dst].set(
+                val, mode="drop").reshape(stack.shape)
+            rows = s
+        new_cache[name] = stack
+        # one dynamic slice of the stack: indexing the layer first and
+        # cutting its rows afterwards makes XLA transpose all S rows
+        views[name] = jax.lax.dynamic_slice(
+            stack, (layer,) + (0,) * (stack.ndim - 1),
+            (1, n, rows) + stack.shape[3:])[0]
+    return new_cache, views
 
 
 def _block_view(cache: jax.Array, block_tables: jax.Array,
@@ -201,23 +224,25 @@ def _block_view(cache: jax.Array, block_tables: jax.Array,
 
 
 def gqa_packed(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
-               cache: dict, token_slot: jax.Array, token_wpos: jax.Array,
-               kv_bucket: Optional[int] = None, token_dst=None,
-               block_tables=None):
+               cache: dict, layer: jax.Array, token_slot: jax.Array,
+               token_wpos: jax.Array, kv_bucket: Optional[int] = None,
+               token_dst=None, block_tables=None):
     """Token-packed dense-batch step (DESIGN.md §8).  x: (1, T, D) — the
     iteration's decode tokens and *all* prefill-chunk tokens packed into one
     stream; positions: (1, T) absolute position of each token in its own
-    request; cache{k,v}: (N_slots, S, KVe, hd) — the *whole* slot cache, not
-    a per-request gather.  Scatters each token's K/V at ``(slot, wpos)``
-    (``wpos == S`` for padding tokens → dropped), then runs segment-masked
-    attention: token t attends rows [0, positions[t]] of its own slot only,
-    which covers the carried prefix *and* same-segment tokens written by
-    this very dispatch.
+    request; cache{k,v}: (L, N_slots, S, KVe, hd) — the group's *stacked*
+    slot cache, carried through the layer loop, of which this is layer
+    ``layer`` (traced).  Scatters each token's K/V at ``(layer, slot,
+    wpos)`` (``wpos == S`` for padding tokens → dropped), then runs
+    segment-masked attention: token t attends rows [0, positions[t]] of its
+    own slot only, which covers the carried prefix *and* same-segment
+    tokens written by this very dispatch.  Returns the updated stack: only
+    the T new rows are written.
 
     ``kv_bucket`` (static, DESIGN.md §9): the engine's bound on this
     iteration's ``max(positions) + 1`` — attention reads only that many
     cache rows per slot, so its FLOPs/bytes scale with actual context, not
-    ``max_len``.  The scatter still targets the full cache.
+    ``max_len``.  The layer's rows are cut from the stack at that length.
 
     Under tensor parallelism (DESIGN.md §11) the projections and the slot
     cache are sharded along (kv-)heads, attention is per-head local, and
@@ -226,9 +251,10 @@ def gqa_packed(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
 
     Block-table mode (DESIGN.md §12, ``token_dst``/``block_tables`` set):
     the same leaves are treated as physical block storage — K/V scatter at
-    flat row ``token_dst[t]`` and attention gathers through the per-slot
-    table, so requests can share immutable prefix blocks.  TP-safe: both
-    reshapes fold the unsharded (slot, seq) axes only.
+    flat row ``token_dst[t]`` of the layer and attention gathers through
+    the per-slot table, so requests can share immutable prefix blocks; it
+    is handed the layer's whole storage, since any block may be in a
+    table.  TP-safe: both reshapes fold the unsharded (slot, seq) axes only.
 
     int8 KV (DESIGN.md §15, scale leaves ``k_s``/``v_s`` present): each
     token's post-rope K/V row quantizes *in-program* at scatter time
@@ -240,39 +266,24 @@ def gqa_packed(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
     if quantized:
         k_val, k_s_new = kvquant.quantize_kv(k_new[0])
         v_val, v_s_new = kvquant.quantize_kv(v_new[0])
+        new_rows = {"k": k_val, "v": v_val, "k_s": k_s_new, "v_s": v_s_new}
     else:
-        k_val, v_val = k_new[0], v_new[0]
-        k_scale = v_scale = None
-    if block_tables is not None:
-        k_cache = _flat_scatter(cache["k"], k_val, token_dst)
-        v_cache = _flat_scatter(cache["v"], v_val, token_dst)
-        if quantized:
-            k_scale = _flat_scatter(cache["k_s"], k_s_new, token_dst)
-            v_scale = _flat_scatter(cache["v_s"], v_s_new, token_dst)
-    else:
-        k_cache = cache["k"].at[token_slot, token_wpos].set(
-            k_val.astype(cache["k"].dtype), mode="drop")
-        v_cache = cache["v"].at[token_slot, token_wpos].set(
-            v_val.astype(cache["v"].dtype), mode="drop")
-        if quantized:
-            k_scale = cache["k_s"].at[token_slot, token_wpos].set(
-                k_s_new, mode="drop")
-            v_scale = cache["v_s"].at[token_slot, token_wpos].set(
-                v_s_new, mode="drop")
-    k_cache = shard(k_cache, "batch", "kv_seq", "act_kv_heads", None)
-    v_cache = shard(v_cache, "batch", "kv_seq", "act_kv_heads", None)
+        new_rows = {"k": k_new[0], "v": v_new[0]}
+    new_cache, view = _write_and_view(cache, layer, new_rows, token_slot,
+                                      token_wpos, kv_bucket, token_dst,
+                                      block_tables)
+    k_cache = shard(view["k"], "batch", "kv_seq", "act_kv_heads", None)
+    v_cache = shard(view["v"], "batch", "kv_seq", "act_kv_heads", None)
+    k_scale = v_scale = None
     if quantized:
-        k_scale = shard(k_scale, "batch", "kv_seq", "act_kv_heads")
-        v_scale = shard(v_scale, "batch", "kv_seq", "act_kv_heads")
+        k_scale = shard(view["k_s"], "batch", "kv_seq", "act_kv_heads")
+        v_scale = shard(view["v_s"], "batch", "kv_seq", "act_kv_heads")
     out = ops.packed_attention(q[0], k_cache, v_cache, token_slot,
                                positions[0] + 1, kv_bucket=kv_bucket,
                                block_tables=block_tables,
                                k_scale=k_scale, v_scale=v_scale)
     y = tp.out_project(out, p["wo"])[None]
     y = shard(y, "batch", "act_seq", "embed")
-    new_cache = {"k": k_cache, "v": v_cache}
-    if quantized:
-        new_cache["k_s"], new_cache["v_s"] = k_scale, v_scale
     return y, new_cache
 
 
@@ -459,17 +470,18 @@ def mla_prefill_chunk(cfg: ModelConfig, p: dict, x: jax.Array,
 
 
 def mla_packed(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
-               cache: dict, token_slot: jax.Array, token_wpos: jax.Array,
-               kv_bucket: Optional[int] = None, token_dst=None,
-               block_tables=None):
+               cache: dict, layer: jax.Array, token_slot: jax.Array,
+               token_wpos: jax.Array, kv_bucket: Optional[int] = None,
+               token_dst=None, block_tables=None):
     """Token-packed step for MLA (DESIGN.md §8): scatter each token's
-    latents at ``(slot, wpos)``, attend absorbed queries over the slot's
-    latent cache with the segment/length mask.  Same absorbed association
+    latents at ``(layer, slot, wpos)`` of the stacked cache (as
+    ``gqa_packed``), attend absorbed queries over the slot's latent cache
+    of that layer with the segment/length mask.  Same absorbed association
     order as every other MLA path.  ``d_v != d_qk`` (latent rank vs
     rank + rope) is handled natively by the packed-attention kernel.
 
-    ``kv_bucket`` (static, DESIGN.md §9) slices the latent views *before*
-    the absorbed-key concat, so the materialized (N, S, rank + rope) key
+    ``kv_bucket`` (static, DESIGN.md §9) cuts the latent views from the
+    stack *before* the absorbed-key concat, so the materialized (N, S, rank + rope) key
     tensor also scales with the bucket, not ``max_len``.
 
     Under tensor parallelism (DESIGN.md §11) the latent path — ``c_kv`` /
@@ -495,43 +507,21 @@ def mla_packed(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
     if quantized:
         c_val, c_s_new = kvquant.quantize_kv(c_new[0])
         r_val, r_s_new = kvquant.quantize_kv(r_new[0])
+        new_rows = {"c_kv": c_val, "k_rope": r_val,
+                    "c_kv_s": c_s_new, "k_rope_s": r_s_new}
     else:
-        c_val, r_val = c_new[0], r_new[0]
-        c_scale = r_scale = None
+        new_rows = {"c_kv": c_new[0], "k_rope": r_new[0]}
+    new_cache, view = _write_and_view(cache, layer, new_rows, token_slot,
+                                      token_wpos, kv_bucket, token_dst,
+                                      block_tables)
+    view["c_kv"] = shard(view["c_kv"], "batch", "kv_seq", None)
     if block_tables is not None:
-        ckv = _flat_scatter(cache["c_kv"], c_val, token_dst)
-        krp = _flat_scatter(cache["k_rope"], r_val, token_dst)
-        if quantized:
-            c_scale = _flat_scatter(cache["c_kv_s"], c_s_new, token_dst)
-            r_scale = _flat_scatter(cache["k_rope_s"], r_s_new, token_dst)
-        ckv = shard(ckv, "batch", "kv_seq", None)
-        ckv_v = _block_view(ckv, block_tables, kv_bucket)
-        krp_v = _block_view(krp, block_tables, kv_bucket)
-        if quantized:
-            c_s_v = _block_view(c_scale, block_tables, kv_bucket)
-            r_s_v = _block_view(r_scale, block_tables, kv_bucket)
-    else:
-        ckv = cache["c_kv"].at[token_slot, token_wpos].set(
-            c_val.astype(cache["c_kv"].dtype), mode="drop")
-        krp = cache["k_rope"].at[token_slot, token_wpos].set(
-            r_val.astype(cache["k_rope"].dtype), mode="drop")
-        if quantized:
-            c_scale = cache["c_kv_s"].at[token_slot, token_wpos].set(
-                c_s_new, mode="drop")
-            r_scale = cache["k_rope_s"].at[token_slot, token_wpos].set(
-                r_s_new, mode="drop")
-        ckv = shard(ckv, "batch", "kv_seq", None)
-        ckv_v, krp_v = ckv, krp
-        c_s_v, r_s_v = c_scale, r_scale
-        if kv_bucket is not None and kv_bucket < ckv.shape[1]:
-            ckv_v = jax.lax.slice_in_dim(ckv, 0, kv_bucket, axis=1)
-            krp_v = jax.lax.slice_in_dim(krp, 0, kv_bucket, axis=1)
-            if quantized:
-                c_s_v = jax.lax.slice_in_dim(c_scale, 0, kv_bucket, axis=1)
-                r_s_v = jax.lax.slice_in_dim(r_scale, 0, kv_bucket, axis=1)
+        view = {name: _block_view(a, block_tables, kv_bucket)
+                for name, a in view.items()}
+    ckv_v, krp_v = view["c_kv"], view["k_rope"]
     if quantized:
-        ckv_v = kvquant.dequantize_kv(ckv_v, c_s_v, x.dtype)
-        krp_v = kvquant.dequantize_kv(krp_v, r_s_v, x.dtype)
+        ckv_v = kvquant.dequantize_kv(ckv_v, view["c_kv_s"], x.dtype)
+        krp_v = kvquant.dequantize_kv(krp_v, view["k_rope_s"], x.dtype)
     k_abs = jnp.concatenate([ckv_v, krp_v], axis=-1)[:, :, None, :]
     v_lat = ckv_v[:, :, None, :]
     scale = (m.qk_nope_dim + m.qk_rope_dim) ** -0.5
@@ -541,9 +531,6 @@ def mla_packed(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
     out = shard(out[None], "batch", "act_seq", "act_heads", None)[0]
     y = tp.out_project(out, p["wo"])[None]
     y = shard(y, "batch", "act_seq", "embed")
-    new_cache = {"c_kv": ckv, "k_rope": krp}
-    if quantized:
-        new_cache["c_kv_s"], new_cache["k_rope_s"] = c_scale, r_scale
     return y, new_cache
 
 

@@ -141,37 +141,46 @@ def block_chunk(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jax.Array,
 
 
 def block_packed(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jax.Array,
-                 positions: jax.Array, cache: dict, token_slot: jax.Array,
-                 token_wpos: jax.Array, token_active: jax.Array,
-                 kv_bucket: Optional[int] = None, token_dst=None,
-                 block_tables=None):
+                 positions: jax.Array, cache: dict, layer: jax.Array,
+                 token_slot: jax.Array, token_wpos: jax.Array,
+                 token_active: jax.Array, kv_bucket: Optional[int] = None,
+                 token_dst=None, block_tables=None):
     """Token-packed dense-batch step (DESIGN.md §8): one (1, T) stream
     holding the iteration's decode tokens and all prefill-chunk tokens with
     per-token (slot, position) metadata, run against the *whole* slot cache.
-    Attention scatters K/V at (slot, wpos), applies the segment mask, and
-    reads only ``kv_bucket`` cache rows per slot (KV-length bucketing,
-    DESIGN.md §9; ``None`` = full ``max_len``); recurrent mixers advance
-    per-slot state with active-masking.  ``token_dst``/``block_tables``
-    switch attention to block-table mode (DESIGN.md §12; attention-only —
-    the engine rejects prefix caching for models with recurrent mixers).
-    Returns (x, new_cache) over the full slot-state arrays."""
+    ``cache`` is the group's stacked cache (leading layer axis), carried
+    through the layer loop; ``layer`` (traced) picks this block's layer.
+    Attention scatters only its T new K/V rows into the stack at (layer,
+    slot, wpos), applies the segment mask, and reads only ``kv_bucket``
+    cache rows per slot (KV-length bucketing, DESIGN.md §9; ``None`` = full
+    ``max_len``); recurrent mixers read their layer's per-slot state,
+    advance it with active-masking, and write the layer back.
+    ``token_dst``/``block_tables`` switch attention to block-table mode
+    (DESIGN.md §12; attention-only — the engine rejects prefix caching for
+    models with recurrent mixers).
+    Returns (x, new stacked cache)."""
     h = rmsnorm(x, p["norm1"], cfg.norm_eps)
     if spec.mixer == ATTN:
         fn = attn.mla_packed if cfg.mla is not None else attn.gqa_packed
-        y, new_cache = fn(cfg, p["mixer"], h, positions, cache, token_slot,
-                          token_wpos, kv_bucket=kv_bucket,
+        y, new_cache = fn(cfg, p["mixer"], h, positions, cache, layer,
+                          token_slot, token_wpos, kv_bucket=kv_bucket,
                           token_dst=token_dst, block_tables=block_tables)
-    elif spec.mixer == MAMBA:
-        y, new_cache = ssm_mod.mamba_packed(cfg, p["mixer"], h, cache,
-                                            token_slot, token_active)
-    elif spec.mixer == MLSTM:
-        y, new_cache = xlstm_mod.mlstm_packed(cfg, p["mixer"], h, cache,
-                                              token_slot, token_active)
-    elif spec.mixer == SLSTM:
-        y, new_cache = xlstm_mod.slstm_packed(cfg, p["mixer"], h, cache,
-                                              token_slot, token_active)
     else:
-        raise ValueError(spec.mixer)
+        if spec.mixer == MAMBA:
+            fn = ssm_mod.mamba_packed
+        elif spec.mixer == MLSTM:
+            fn = xlstm_mod.mlstm_packed
+        elif spec.mixer == SLSTM:
+            fn = xlstm_mod.slstm_packed
+        else:
+            raise ValueError(spec.mixer)
+        state = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+            cache)
+        y, state = fn(cfg, p["mixer"], h, state, token_slot, token_active)
+        new_cache = jax.tree.map(
+            lambda a, s: jax.lax.dynamic_update_index_in_dim(a, s, layer, 0),
+            cache, state)
     x = x + y
     x, _aux = _ffn_apply(cfg, spec, p, x)
     return x, new_cache

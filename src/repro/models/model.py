@@ -373,7 +373,8 @@ def forward_packed(cfg: ModelConfig, params: dict, tokens: jax.Array,
     function runs — the semantics here are unchanged, the values just never
     round-tripped through the host.
 
-    Attention writes each token's K/V (MLA latents) at ``(slot, pos)`` and
+    Attention writes each token's K/V (MLA latents) at ``(layer, slot,
+    pos)`` of the group's stacked cache, which the layer loop carries, and
     applies a segment-aware mask — a token attends rows ``[0, pos]`` of its
     own slot only, so segments never attend across each other; recurrent
     mixers advance per-slot state through a token scan with active-masking.
@@ -404,22 +405,25 @@ def forward_packed(cfg: ModelConfig, params: dict, tokens: jax.Array,
     new_cache: list = []
     for gi, (pattern, reps) in enumerate(cfg.layer_groups()):
         stacked_p = params[f"group{gi}"]
-        stacked_c = cache[gi]
 
-        def body(x, pc, _pattern=pattern):
-            layer_p, layer_c = pc
-            new_c = {}
+        def body(carry, xs, _pattern=pattern):
+            x, group_c = carry
+            layer_p, layer = xs
+            group_c = dict(group_c)
             for i, spec in enumerate(_pattern):
-                x, c = blocks.block_packed(cfg, spec, layer_p[f"sub{i}"], x,
-                                           positions, layer_c[f"sub{i}"],
-                                           token_slot, token_wpos,
-                                           token_active, kv_bucket=kv_bucket,
-                                           token_dst=token_dst,
-                                           block_tables=block_tables)
-                new_c[f"sub{i}"] = c
-            return x, new_c
+                x, group_c[f"sub{i}"] = blocks.block_packed(
+                    cfg, spec, layer_p[f"sub{i}"], x, positions,
+                    group_c[f"sub{i}"], layer, token_slot, token_wpos,
+                    token_active, kv_bucket=kv_bucket, token_dst=token_dst,
+                    block_tables=block_tables)
+            return (x, group_c), None
 
-        x, nc = jax.lax.scan(body, x, (stacked_p, stacked_c))
+        # the stacked cache is carried, not scanned: as a scanned input and
+        # output XLA copies the whole stack and every layer slices out and
+        # writes back its whole cache; carried, each layer writes its T new
+        # rows in place (DESIGN.md §8)
+        (x, nc), _ = jax.lax.scan(
+            body, (x, cache[gi]), (stacked_p, jnp.arange(reps)))
         new_cache.append(nc)
     logits = _head(cfg, params, x)
     return logits, new_cache

@@ -4,6 +4,8 @@ Covers the tentpole invariants:
   * ``forward_packed`` over a mixed stream (decode token + two prefill
     chunks + padding, all in one call) == per-request ``forward_decode`` /
     ``forward_chunk`` references, across every mixer family;
+  * the stacked cache carried through the layer loop: each layer writes
+    its own new rows and nothing else, contiguous and block-table;
   * engine packed step == legacy per-chunk step end-to-end (f32 so op-order
     rounding can't flip MoE routing), through slot reuse;
   * exactly one jitted model dispatch and one device→host transfer per
@@ -23,6 +25,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, scale_down
+from repro.configs.base import ATTN
 from repro.core.nanobatch import NanoBatchPlan, packed_segment_order
 from repro.models import model
 from repro.serving.engine import ServeEngine
@@ -68,6 +71,54 @@ def family(request):
     return cfg, params
 
 
+def _mixed_stream(cfg, max_len):
+    """The packed stream: slot0 decode @4, slot1 chunk [4,9), slot2 chunk
+    [4,6), 2 padding tokens -> T = 10 (any further slot gets no token)."""
+    dec = _tokens(cfg, jax.random.PRNGKey(2), 1, 1)
+    ch1 = _tokens(cfg, jax.random.PRNGKey(3), 1, 5)
+    ch2 = _tokens(cfg, jax.random.PRNGKey(4), 1, 2)
+    pad = jnp.zeros_like(_tokens(cfg, jax.random.PRNGKey(5), 1, 2))
+    stream = jnp.concatenate([dec, ch1, ch2, pad], axis=1)
+    slot = jnp.asarray([0] + [1] * 5 + [2] * 2 + [0] * 2, jnp.int32)
+    pos = jnp.asarray([4, 4, 5, 6, 7, 8, 4, 5, 0, 0], jnp.int32)
+    active = jnp.asarray([True] * 8 + [False] * 2)
+    wpos = jnp.where(active, pos, max_len)
+    return (dec, ch1, ch2), stream, slot, pos, wpos, active
+
+
+def _per_request_reference(cfg, params, cache, segments, pre):
+    """Per-request decode/chunk references on gathered one-slot caches for
+    ``_mixed_stream``'s three segments, each after ``pre`` cached tokens.
+    Returns (logits of the 8 real tokens, [new one-slot cache per slot])."""
+    dec, ch1, ch2 = segments
+    clen = jnp.full((1,), pre, jnp.int32)
+    ref_dec, ref_dec_cache = model.forward_decode(
+        cfg, params, dec, _gather_slot(cache, 0), clen)
+    ref1, ref1_cache = model.forward_chunk(
+        cfg, params, ch1, _gather_slot(cache, 1), clen)
+    ref2, ref2_cache = model.forward_chunk(
+        cfg, params, ch2, _gather_slot(cache, 2), clen)
+    ref = jnp.concatenate([ref_dec[:, None], ref1, ref2], axis=1)
+    return ref, [ref_dec_cache, ref1_cache, ref2_cache]
+
+
+def _assert_logits_match(cfg, got, ref):
+    err = float(jnp.abs(got.astype(jnp.float32)
+                        - ref.astype(jnp.float32)).max())
+    scale = float(jnp.abs(ref.astype(jnp.float32)).max()) + 1e-6
+    # f32: exact up to einsum-order rounding (the recurrent families are
+    # bit-identical; attention differs in reduction order, and the MoE
+    # router amplifies those ulps into slightly different expert weights) —
+    # a real masking/offset bug would be O(scale)
+    assert err <= max(1e-3 * scale, 1e-4), (cfg.name, err, scale)
+
+
+def _assert_close(got, ref, what):
+    got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+    tol = max(1e-3 * (np.abs(ref).max() + 1e-6), 1e-4)
+    assert np.abs(got - ref).max() <= tol, what
+
+
 def test_forward_packed_matches_per_request_reference(family):
     """One packed call carrying a decode token (slot 0), a deep prefill
     chunk (slot 1), a short chunk (slot 2), and padding == the per-request
@@ -80,56 +131,111 @@ def test_forward_packed_matches_per_request_reference(family):
     prefix = _tokens(cfg, jax.random.PRNGKey(1), 3, pre)
     _, cache = model.forward_chunk(cfg, params, prefix, cache,
                                    jnp.zeros((3,), jnp.int32))
-    clen = jnp.full((3,), pre, jnp.int32)
 
-    # the packed stream: slot0 decode @4, slot1 chunk [4,9), slot2 chunk
-    # [4,6), 2 padding tokens -> T = 10
-    dec = _tokens(cfg, jax.random.PRNGKey(2), 1, 1)
-    ch1 = _tokens(cfg, jax.random.PRNGKey(3), 1, 5)
-    ch2 = _tokens(cfg, jax.random.PRNGKey(4), 1, 2)
-    pad = jnp.zeros_like(_tokens(cfg, jax.random.PRNGKey(5), 1, 2))
-    stream = jnp.concatenate([dec, ch1, ch2, pad], axis=1)
-    slot = jnp.asarray([0] + [1] * 5 + [2] * 2 + [0] * 2, jnp.int32)
-    pos = jnp.asarray([4, 4, 5, 6, 7, 8, 4, 5, 0, 0], jnp.int32)
-    active = jnp.asarray([True] * 8 + [False] * 2)
-    wpos = jnp.where(active, pos, max_len)
-
+    segments, stream, slot, pos, wpos, active = _mixed_stream(cfg, max_len)
     logits, new_cache = model.forward_packed(cfg, params, stream, cache,
                                              slot, pos, wpos, active)
-
-    # per-request references on gathered one-slot caches
-    ref_dec, ref_dec_cache = model.forward_decode(
-        cfg, params, dec, _gather_slot(cache, 0), clen[:1])
-    ref1, ref1_cache = model.forward_chunk(
-        cfg, params, ch1, _gather_slot(cache, 1), clen[1:2])
-    ref2, ref2_cache = model.forward_chunk(
-        cfg, params, ch2, _gather_slot(cache, 2), clen[2:3])
-
-    ref = jnp.concatenate([ref_dec[:, None], ref1, ref2], axis=1)
-    got = logits[:, :8]
-    err = float(jnp.abs(got.astype(jnp.float32)
-                        - ref.astype(jnp.float32)).max())
-    scale = float(jnp.abs(ref.astype(jnp.float32)).max()) + 1e-6
-    # f32: exact up to einsum-order rounding (the recurrent families are
-    # bit-identical; attention differs in reduction order, and the MoE
-    # router amplifies those ulps into slightly different expert weights) —
-    # a real masking/offset bug would be O(scale)
-    assert err <= max(1e-3 * scale, 1e-4), (cfg.name, err, scale)
+    ref, ref_caches = _per_request_reference(cfg, params, cache, segments,
+                                             pre)
+    _assert_logits_match(cfg, logits[:, :8], ref)
 
     # committed state: each slot's recurrent carry matches its reference;
     # padding committed nothing (slot 0's state untouched by the pad tokens)
     for gi, (pattern, reps) in enumerate(cfg.layer_groups()):
         for i, spec in enumerate(pattern):
             got_sub = new_cache[gi][f"sub{i}"]
-            for si, ref_cache in ((0, ref_dec_cache), (1, ref1_cache),
-                                  (2, ref2_cache)):
+            for si, ref_cache in enumerate(ref_caches):
                 ref_sub = ref_cache[gi][f"sub{i}"]
                 for name, leaf in got_sub.items():
-                    g = np.asarray(leaf[:, si], np.float32)
-                    r = np.asarray(ref_sub[name][:, 0], np.float32)
-                    tol = max(1e-3 * (np.abs(r).max() + 1e-6), 1e-4)
-                    assert np.abs(g - r).max() <= tol, \
-                        (cfg.name, gi, i, name, si)
+                    _assert_close(leaf[:, si], ref_sub[name][:, 0],
+                                  (cfg.name, gi, i, name, si))
+
+
+BLOCK = 4
+
+
+@pytest.mark.parametrize("mode", ["contiguous", "block-table"])
+def test_forward_packed_writes_each_layers_new_rows_only(family, mode):
+    """The stacked cache is carried through the layer loop and every layer
+    writes only its own T new rows.  From a cache whose attention rows are
+    all random (past each slot's context too): every layer's rows at
+    (slot, wpos) hold that layer's new K/V (latents) of the per-request
+    reference, every other row of every layer is bit-for-bit the input, a
+    slot with no tokens keeps its recurrent state bit-for-bit, and the
+    logits match the reference.  In block-table mode the slots' blocks sit
+    at permuted physical blocks and the checks run on the logical view."""
+    cfg, params = family
+    n, max_len, pre, kv_bucket = 4, 16, 4, 12
+    # recurrent state from a random 4-token prefix in each slot; attention
+    # leaves random in every row of every layer
+    cache = model.init_cache(cfg, 1, n, max_len)
+    prefix = _tokens(cfg, jax.random.PRNGKey(1), n, pre)
+    _, cache = model.forward_chunk(cfg, params, prefix, cache,
+                                   jnp.zeros((n,), jnp.int32))
+    attn_subs = [{f"sub{i}" for i, spec in enumerate(pattern)
+                  if spec.mixer == ATTN}
+                 for pattern, _ in cfg.layer_groups()]
+    keys = iter(jax.random.split(jax.random.PRNGKey(6), 64))
+    cache = [{sub: ({name: jax.random.normal(next(keys), a.shape, a.dtype)
+                     for name, a in c.items()}
+                    if sub in attn_subs[gi] else c)
+              for sub, c in group.items()}
+             for gi, group in enumerate(cache)]
+
+    segments, stream, slot, pos, wpos, active = _mixed_stream(cfg, max_len)
+    kw = {}
+    if mode == "block-table":
+        nb = max_len // BLOCK
+        tables = jnp.asarray(np.random.default_rng(0).permutation(n * nb)
+                             .reshape(n, nb), jnp.int32)
+        kw = dict(block_tables=tables, token_dst=jnp.where(
+            active, tables[slot, pos // BLOCK] * BLOCK + pos % BLOCK,
+            n * max_len))
+
+    def logical(tree):
+        """Attention leaves as per-slot rows (L, N, S, ...)."""
+        if mode == "contiguous":
+            return tree
+
+        def view(a):
+            blocks = a.reshape((a.shape[0], n * nb, BLOCK) + a.shape[3:])
+            return blocks[:, kw["block_tables"]].reshape(a.shape)
+        return [{sub: (jax.tree.map(view, c) if sub in attn_subs[gi] else c)
+                 for sub, c in group.items()}
+                for gi, group in enumerate(tree)]
+
+    logits, new_cache = model.forward_packed(
+        cfg, params, stream, cache, slot, pos, wpos, active,
+        kv_bucket=kv_bucket, **kw)
+    before, after = logical(cache), logical(new_cache)
+    ref, ref_caches = _per_request_reference(cfg, params, before, segments,
+                                             pre)
+    _assert_logits_match(cfg, logits[:, :8], ref)
+
+    written = np.zeros((n, max_len), bool)
+    rows = [(int(s), int(p)) for s, p, a in zip(slot, pos, active) if a]
+    for s, p in rows:
+        written[s, p] = True
+    for gi, (pattern, _) in enumerate(cfg.layer_groups()):
+        for i, spec in enumerate(pattern):
+            sub = f"sub{i}"
+            for name, leaf in after[gi][sub].items():
+                got = np.asarray(leaf)
+                inp = np.asarray(before[gi][sub][name])
+                what = (cfg.name, mode, gi, sub, name)
+                if spec.mixer == ATTN:
+                    np.testing.assert_array_equal(
+                        got[:, ~written], inp[:, ~written], err_msg=str(what))
+                    for s, p in rows:
+                        _assert_close(got[:, s, p],
+                                      ref_caches[s][gi][sub][name][:, 0, p],
+                                      what + (s, p))
+                else:
+                    np.testing.assert_array_equal(got[:, 3], inp[:, 3],
+                                                  err_msg=str(what))
+                    for s, ref_cache in enumerate(ref_caches):
+                        _assert_close(got[:, s], ref_cache[gi][sub][name][:, 0],
+                                      what + (s,))
 
 
 def test_engine_packed_matches_legacy(family):

@@ -11,6 +11,8 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
 file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -25,6 +27,9 @@ from repro.serving import sampling
 V5E_HBM_BYTES = int(15.75 * 2**30)
 # the sizes chip_smoke.py serves Qwen3-4B at on one chip
 SMOKE_SLOTS, SMOKE_MAX_LEN, SMOKE_T, SMOKE_KV_BUCKET = 8, 2048, 256, 1024
+# an HLO instruction's result type and opcode: "= bf16[36,8]{1,0} copy("
+_RESULT_OP = re.compile(r"= (\w+\[[\d,]*\])\{[^}]*\} ([\w-]+)\(")
+_HLO_DTYPE = {"bfloat16": "bf16", "int8": "s8", "float32": "f32"}
 
 
 @pytest.fixture(scope="module")
@@ -136,9 +141,25 @@ def test_qwen3_4b_packed_step_fits_one_v5e(one_chip, kv_dtype):
                         vec if tables is not None else None, tables,
                         donate=(1,))
     mem = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
     # the engine also keeps a one-slot fresh cache beside the step
-    slot_init = sum(a.size * a.dtype.itemsize
-                    for a in jax.tree.leaves(cache)) // SMOKE_SLOTS
+    slot_init = cache_bytes // SMOKE_SLOTS
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes + slot_init)
     assert used <= V5E_HBM_BYTES, (used / 2**30, mem)
+
+    # the stacked cache is carried through the layer loop and each layer
+    # writes its new rows in place (DESIGN.md §8): no copy of a whole
+    # stack and no write-back of a whole layer into one (as scanned input
+    # and output, 2.28 GiB of temporaries at these sizes) ...
+    stack_types = {f"{_HLO_DTYPE[a.dtype.name]}[{','.join(map(str, a.shape))}]"
+                   for a in jax.tree.leaves(cache)}
+    whole_stack_ops = [
+        m.group(0) for m in _RESULT_OP.finditer(compiled.as_text())
+        if m.group(1) in stack_types
+        and m.group(2) in ("copy", "dynamic-update-slice")]
+    assert not whole_stack_ops, whole_stack_ops[:4]
+    assert mem.temp_size_in_bytes < 2**29, mem.temp_size_in_bytes / 2**30
+    # ... and the donated cache is still updated in place
+    assert mem.alias_size_in_bytes == cache_bytes, mem
