@@ -67,18 +67,14 @@ def _submit_workload(eng, name: str, p: int, d: int, n_requests: int,
                            max_new_tokens=min(dlen, d_cap)))
 
 
-# step-mode A/B matrix (DESIGN.md §8-§10): the async pipelined packed step
-# (scheduling overlaps device compute, sampled tokens synced one iteration
-# late) vs the same step retired eagerly, vs the packed step sweeping the
-# full max_len cache (the pre-§9 baseline), vs the legacy
-# decode-then-per-chunk step, plus the O(p²/chunk) recompute baseline
+# packed-step A/B matrix (DESIGN.md §8-§10): the async pipelined packed
+# step (scheduling overlaps device compute, sampled tokens synced one
+# iteration late) vs the same step retired eagerly, vs the packed step
+# sweeping the full max_len cache (the pre-§9 baseline)
 ENGINE_MODES = [
-    ("packed-async", {"step_mode": "packed", "async_depth": 1}),
-    ("packed", {"step_mode": "packed", "async_depth": 0}),
-    ("packed-dense-kv", {"step_mode": "packed", "async_depth": 0,
-                         "kv_bucketing": False}),
-    ("legacy", {"step_mode": "legacy"}),
-    ("recompute", {"step_mode": "legacy", "prefill_mode": "recompute"}),
+    ("packed-async", {"async_depth": 1}),
+    ("packed", {"async_depth": 0}),
+    ("packed-dense-kv", {"async_depth": 0, "kv_bucketing": False}),
 ]
 
 
@@ -87,12 +83,10 @@ def engine_measured(n_requests: int = 16,
     """Real engine runs, A/B-ing the asynchronously pipelined packed step
     (DESIGN.md §10, ``async_depth=1`` — iteration i+1 is formed and
     launched before iteration i's sampled tokens are retrieved) against
-    the eager kv-bucketed packed step, the same packed step sweeping the
-    full ``max_len`` cache every iteration (the PR-2/DESIGN.md-§8
-    baseline, ``kv_bucketing=False``), the legacy decode + per-chunk
-    step, and the prefix-recompute baseline (O(p²/chunk), DESIGN.md §7).
-    Each mode runs the workload twice and reports the second (warmed)
-    pass, so XLA compile time — which differs between the modes'
+    the eager kv-bucketed packed step and the same packed step sweeping
+    the full ``max_len`` cache every iteration (the DESIGN.md-§8
+    baseline, ``kv_bucketing=False``).  Each mode runs the workload twice
+    and reports the second (warmed) pass, so XLA compile time — which differs between the modes'
     compile-cache footprints — doesn't pollute the A/B.  Reported per
     mode: tokens/s, dispatches/iteration, host syncs/iteration, prefill
     expansion, the packed step's bucketing-padding fraction, the
@@ -106,8 +100,8 @@ def engine_measured(n_requests: int = 16,
     rows = []
     # prompt:decode ratios scaled from the paper's workloads (splitwise
     # 1155:211 ≈ 5:1 prefill-heavy, sharegpt 246:322 decode-leaning); 8
-    # slots so iterations carry several concurrent prefill chunks — the
-    # dense-batch regime where the legacy step pays 1 + K dispatches.
+    # slots so iterations carry several concurrent prefill chunks beside
+    # the decodes in one packed launch.
     # "longctx-like" provisions a 512-token cache but serves mixed-length
     # contexts — the regime §9's kv bucketing targets: the dense baseline
     # sweeps slots × 512 rows every iteration regardless of actual context
@@ -120,8 +114,7 @@ def engine_measured(n_requests: int = 16,
             # mode config on top of the CLI base (EngineConfig satellite:
             # one shared validated surface; the A/B matrix pins its own
             # axes, the base supplies attention toggles etc.)
-            mode_kw = dict(step_mode=None, async_depth=None,
-                           prefill_mode="incremental", kv_bucketing=True,
+            mode_kw = dict(async_depth=None, kv_bucketing=True,
                            prefix_caching=False, tp=1)
             mode_kw.update(kwargs)
             ecfg = dataclasses.replace(
@@ -152,16 +145,14 @@ def engine_measured(n_requests: int = 16,
             wall = st.wall_time - warm.wall_time
             # second measured pass, best-of taken: single-core CPU wall
             # times swing 2-3x under scheduler noise — best-of-2 keeps the
-            # mode-vs-mode ratios honest without a longer run (the slow
-            # recompute baseline is left at one pass; it sits 20-60x off)
-            if mode != "recompute":
-                _submit_workload(eng, name, p, d, n_req, cfg.vocab_size,
-                                 2 * n_req, p_cap=p_cap, d_cap=d_cap)
-                eng.run()
-                tok2 = eng.stats.total_tokens - st.total_tokens
-                wall2 = eng.stats.wall_time - st.wall_time
-                if tok2 / max(wall2, 1e-9) > tokens / max(wall, 1e-9):
-                    tokens, wall = tok2, wall2
+            # mode-vs-mode ratios honest without a longer run
+            _submit_workload(eng, name, p, d, n_req, cfg.vocab_size,
+                             2 * n_req, p_cap=p_cap, d_cap=d_cap)
+            eng.run()
+            tok2 = eng.stats.total_tokens - st.total_tokens
+            wall2 = eng.stats.wall_time - st.wall_time
+            if tok2 / max(wall2, 1e-9) > tokens / max(wall, 1e-9):
+                tokens, wall = tok2, wall2
             iters = st.iterations - warm.iterations
             prefill_tok = st.prefill_tokens - warm.prefill_tokens
             model_tok = st.prefill_model_tokens - warm.prefill_model_tokens
@@ -209,15 +200,10 @@ def engine_measured(n_requests: int = 16,
                 "dispatch_s": round(st.dispatch_time - warm.dispatch_time, 3),
                 "overshoot_tokens": dropped,
             }
-        pk, leg = per_mode["packed"], per_mode["legacy"]
+        pk = per_mode["packed"]
         pk["speedup_vs_dense_kv"] = round(
             pk["_tok_s_raw"]
             / max(per_mode["packed-dense-kv"]["_tok_s_raw"], 1e-9), 3)
-        pk["speedup_vs_legacy"] = round(
-            pk["_tok_s_raw"] / max(leg["_tok_s_raw"], 1e-9), 3)
-        pk["speedup_vs_recompute"] = round(
-            pk["_tok_s_raw"] / max(per_mode["recompute"]["_tok_s_raw"], 1e-9),
-            3)
         # §10 async-vs-eager axis: same packed program, same dispatch/sync
         # counts — the delta is the host/device overlap
         per_mode["packed-async"]["speedup_vs_eager"] = round(
@@ -243,8 +229,7 @@ def engine_tp_ab(tp: int, n_requests: int = 12) -> list[dict]:
     for tp_deg in (1, tp):
         eng = ServeEngine(cfg, params, EngineConfig(
             max_slots=8, max_len=max_len, discrete_sizes=(64, 32, 16, 8),
-            avg_decode_len=float(d), step_mode="packed", async_depth=1,
-            tp=tp_deg))
+            avg_decode_len=float(d), async_depth=1, tp=tp_deg))
         _submit_workload(eng, name, p, d, n_requests, cfg.vocab_size, 0)
         eng.run()                                  # warmup: compiles all
         warm = dataclasses.replace(eng.stats)
@@ -309,8 +294,8 @@ def engine_prefix_ab(n_requests: int = 12,
         ecfg = dataclasses.replace(
             base, max_slots=8, max_len=128, kv_block_size=16,
             discrete_sizes=(64, 32, 16, 8), avg_decode_len=float(d),
-            step_mode="packed", async_depth=1, prefill_mode="incremental",
-            kv_bucketing=True, kv_buckets=None, prefix_caching=pc, tp=1,
+            async_depth=1, kv_bucketing=True, kv_buckets=None,
+            prefix_caching=pc, tp=1,
             total_pages=None, kv_budget_bytes=None)
         eng = ServeEngine(cfg, params, ecfg)
         # priming pass: completes one shared-prompt request (commits +
@@ -396,8 +381,7 @@ def engine_spec_ab(n_requests: int = 10, spec_k: int = 4,
     for k in (0, spec_k):
         ecfg = dataclasses.replace(
             base, max_slots=8, max_len=128, discrete_sizes=(64, 32, 16, 8),
-            avg_decode_len=float(d), step_mode="packed", async_depth=1,
-            prefill_mode="incremental", kv_bucketing=True,
+            avg_decode_len=float(d), async_depth=1, kv_bucketing=True,
             prefix_caching=False, tp=1, spec_k=k,
             drafter="ngram" if k else None, temperature=0.0, top_k=None)
         eng = ServeEngine(cfg, params, ecfg)
@@ -496,8 +480,8 @@ def engine_kvdtype_ab(n_requests: int = 10,
         ecfg = dataclasses.replace(
             base, max_slots=8, max_len=max_len,
             discrete_sizes=(64, 32, 16, 8), avg_decode_len=float(d),
-            step_mode="packed", async_depth=1, prefill_mode="incremental",
-            kv_bucketing=True, prefix_caching=False, tp=1, spec_k=0,
+            async_depth=1, kv_bucketing=True, prefix_caching=False, tp=1,
+            spec_k=0,
             total_pages=None, kv_budget_bytes=budget, kv_dtype=kd)
         eng = ServeEngine(cfg, params, ecfg)
         # warmup: identical workload -> compiles every (T, kv) program
@@ -666,10 +650,8 @@ def main(argv=None) -> None:
                   f"{r['cow_copies']} CoW){extra}")
         else:
             extra = ""
-            if "speedup_vs_legacy" in r:
-                extra = (f" [{r['speedup_vs_dense_kv']}x vs dense-kv, "
-                         f"{r['speedup_vs_legacy']}x vs legacy, "
-                         f"{r['speedup_vs_recompute']}x vs recompute]")
+            if "speedup_vs_dense_kv" in r:
+                extra = f" [{r['speedup_vs_dense_kv']}x vs dense-kv]"
             if "speedup_vs_eager" in r:
                 extra = (f" [depth {r['async_depth']}: "
                          f"{r['speedup_vs_eager']}x vs eager packed, "

@@ -186,7 +186,7 @@ def main() -> None:
     print(f"compiles in dispatch: {st['compiles']} "
           f"({st['compile_time']:.2f} s) by (launch tokens, kv bucket): "
           f"{dict(sorted(st['compile_hist'].items()))}")
-    print(f"step={eng.step_mode}: {st['dispatches_per_iter']:.2f} "
+    print(f"{st['dispatches_per_iter']:.2f} "
           f"dispatches/iter, {st['syncs_per_iter']:.2f} host syncs/iter, "
           f"{st['packed_pad_tokens']} pad tokens")
     print(f"async depth {eng.async_depth}: "

@@ -159,7 +159,7 @@ def gqa_prefill_chunk(cfg: ModelConfig, p: dict, x: jax.Array,
     cache with a ``q_offset`` causal mask — positions beyond
     cache_len + S_chunk are never written yet, so the mask excludes them.
     Each prompt token is projected exactly once across chunks (O(p) FLOPs
-    instead of the recompute path's O(p²/chunk))."""
+    instead of the O(p²/chunk) of re-running the prefix per chunk)."""
     q, k_new, v_new = _qkv(cfg, p, x, positions)
     k_cache = _write_seq_at(cache["k"], k_new, cache_len)
     v_cache = _write_seq_at(cache["v"], v_new, cache_len)

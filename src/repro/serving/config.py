@@ -3,15 +3,15 @@
 
 Design rules:
 
-  * **Fields store what the caller said** — ``step_mode=None`` stays
+  * **Fields store what the caller said** — ``async_depth=None`` stays
     ``None``; the ``resolved_*`` accessors apply the defaulting rules
-    (packed step for incremental prefill, async depth 1 for the packed
-    step, env fallbacks for the attention toggles).  This keeps
+    (async depth 1, the n-gram drafter under speculation, env fallbacks
+    for the attention toggles).  This keeps
     ``dataclasses.replace`` composable: overriding one field never bakes a
     stale resolution of another into the copy.
   * **Validation lives in ``__post_init__``** — every invariant the engine
-    used to assert at construction (mode combinations, tp/packed coupling,
-    block-size divisibility for prefix caching) fails fast here, before any
+    used to assert at construction (value ranges, block-size divisibility
+    for prefix caching, spec_k below max_len) fails fast here, before any
     device work.
   * **Env is read at construction, never at trace time** — the
     ``REPRO_ATTN_FAST`` / ``REPRO_ATTN_STREAM`` fallbacks are captured by
@@ -53,9 +53,7 @@ class EngineConfig:
     discrete_sizes: tuple[int, ...] = (256, 128, 64, 32, 16, 8)
     nano: int = 2
     # ---- step / pipeline ----------------------------------------------------
-    prefill_mode: str = "incremental"
-    step_mode: Optional[str] = None          # None -> packed iff incremental
-    async_depth: Optional[int] = None        # None -> 1 packed / 0 legacy
+    async_depth: Optional[int] = None        # None -> 1
     async_harvest: bool = True
     tp: int = 1
     # ---- KV-length bucketing (DESIGN.md §9) ---------------------------------
@@ -83,35 +81,16 @@ class EngineConfig:
 
     # ------------------------------------------------------------------------
     def __post_init__(self):
-        assert self.prefill_mode in ("incremental", "recompute"), \
-            self.prefill_mode
-        step = self.resolved_step_mode
-        assert step in ("packed", "legacy"), step
-        assert not (step == "packed" and self.prefill_mode == "recompute"), \
-            "packed step runs incremental prefill only"
         assert self.tp >= 1, self.tp
-        assert self.tp == 1 or step == "packed", \
-            "tensor-parallel serving (DESIGN.md §11) requires the packed step"
-        depth = self.resolved_async_depth
-        assert depth >= 0, depth
-        assert depth == 0 or step == "packed", \
-            "the async pipeline (DESIGN.md §10) requires the packed step"
+        assert self.resolved_async_depth >= 0, self.async_depth
         assert self.kv_block_size >= 1, self.kv_block_size
         assert self.max_slots >= 1 and self.max_len >= 1
         if self.prefix_caching:
-            assert step == "packed", \
-                "prefix caching (DESIGN.md §12) requires the packed step"
             assert self.max_len % self.kv_block_size == 0, \
                 (self.max_len, self.kv_block_size)
         assert self.kv_dtype in ("bf16", "int8"), self.kv_dtype
-        if self.kv_dtype == "int8":
-            assert step == "packed", \
-                "int8 KV (DESIGN.md §15) requires the packed step — the " \
-                "legacy decode/chunk paths write native-dtype rows"
         assert self.spec_k >= 0, self.spec_k
         if self.spec_k > 0:
-            assert step == "packed", \
-                "speculative decoding (DESIGN.md §13) requires the packed step"
             assert self.spec_k < self.max_len, (self.spec_k, self.max_len)
         if self.drafter is not None:
             from repro.serving.draft import drafter_names
@@ -126,20 +105,9 @@ class EngineConfig:
 
     # ---- defaulting rules (never baked into the stored fields) --------------
     @property
-    def resolved_step_mode(self) -> str:
-        if self.step_mode is not None:
-            return self.step_mode
-        # the recompute prefill path has no packed equivalent — A/B runs
-        # that ask for it get the legacy per-chunk step automatically
-        return "packed" if self.prefill_mode == "incremental" else "legacy"
-
-    @property
     def resolved_async_depth(self) -> int:
-        if self.async_depth is not None:
-            return int(self.async_depth)
-        # the pipeline is the default serving mode (§5.3 / DESIGN.md §10);
-        # the legacy step has no deferred-sync path
-        return 1 if self.resolved_step_mode == "packed" else 0
+        # the pipeline is the default serving mode (§5.3 / DESIGN.md §10)
+        return int(self.async_depth) if self.async_depth is not None else 1
 
     @property
     def resolved_drafter(self) -> Optional[str]:
@@ -189,14 +157,10 @@ class EngineConfig:
                         help="slot count (concurrent active requests)")
         ap.add_argument("--max-len", type=int, default=256,
                         help="per-slot cache capacity (tokens)")
-        ap.add_argument("--step-mode", default="packed",
-                        choices=["packed", "legacy"],
-                        help="packed = one fused dispatch/iteration "
-                             "(DESIGN.md §8)")
         ap.add_argument("--async-depth", type=int, default=None,
                         help="iterations kept in flight before syncing their "
                              "sampled tokens (DESIGN.md §10); 0 = eager "
-                             "lock-step; default: 1 packed / 0 legacy")
+                             "lock-step; default: 1")
         ap.add_argument("--tp", type=int, default=1,
                         help="tensor-parallel degree (DESIGN.md §11): the "
                              "packed step runs as one shard_map program over "
@@ -250,7 +214,6 @@ class EngineConfig:
         kw = dict(
             max_slots=ns.slots,
             max_len=ns.max_len,
-            step_mode=ns.step_mode,
             async_depth=ns.async_depth,
             tp=ns.tp,
             kv_bucketing=not ns.no_kv_bucketing,

@@ -5,7 +5,7 @@ Slot-based execution: model state lives in fixed-capacity slot caches
 insight applied to the XLA compilation cache).  Prefill runs in chunks
 (chunked prefill, §4.2) whose KV states are written into the request's slot.
 
-**Packed step (default, DESIGN.md §8).**  One iteration = one jitted
+**Packed step (DESIGN.md §8).**  One iteration = one jitted
 program: the decode tokens (one per decoding slot) and *all* scheduled
 prefill chunks are packed into a single ``(1, T)`` token stream with
 per-token ``(slot, position)`` metadata and run through
@@ -13,8 +13,7 @@ per-token ``(slot, position)`` metadata and run through
 offset, a segment-aware mask so segments never attend across each other,
 recurrent state advanced per-slot with active-masking, greedy sampling
 on-device.  Exactly one model dispatch and one device→host transfer per
-iteration (``EngineStats.model_dispatches`` / ``host_syncs``), vs the
-legacy path's ``1 + K`` dispatches with a blocking sync per chunk.  ``T``
+iteration (``EngineStats.model_dispatches`` / ``host_syncs``).  ``T``
 is bucketed to the scheduler's discrete dense sizes, so
 ``BatchPlan.dense_batch`` is the *actual launched shape*; the iteration's
 max KV extent is quantized to a KV-length bucket grid (DESIGN.md §9) and
@@ -40,12 +39,6 @@ defaults to ``async_depth=1``; ``async_depth=0`` retires each iteration
 immediately and is bit-identical to the pre-§10 lock-step engine (the
 A/B baseline).  ``EngineStats`` splits the wall clock into host work /
 dispatch / blocked-sync time so the overlap is measurable.
-
-**Legacy step (``step_mode="legacy"``, kept for A/B).**  Decode first over
-all slots, then one ``model.forward_chunk`` dispatch per prefill chunk,
-each gathering/scattering the chunk's slot sub-cache (DESIGN.md §7).  The
-pre-§7 recompute path (O(p²/chunk) FLOPs) remains as
-``prefill_mode="recompute"`` (implies the legacy step).
 
 **Tensor-parallel serving (``tp=N``, DESIGN.md §11).**  The same packed
 step runs as **one ``shard_map`` program** over a 1-D ``("model",)`` mesh:
@@ -131,14 +124,12 @@ def kv_bytes_per_token(cfg: ModelConfig,
 class EngineStats:
     iterations: int = 0
     prefill_tokens: int = 0          # prompt tokens admitted to the cache
-    prefill_model_tokens: int = 0    # token-positions actually run through
-    #                                  the model during prefill: == prefill
-    #                                  _tokens on the incremental path (O(p)),
-    #                                  strictly greater on the recompute path
+    prefill_model_tokens: int = 0    # model token-positions run during
+    #                                  prefill; must equal prefill_tokens
+    #                                  (linear prefill, O(p) per prompt)
     decode_tokens: int = 0
     wall_time: float = 0.0
-    # host/device overlap split (DESIGN.md §10; replaces the old
-    # ``prefill_time``, which only the legacy step ever updated):
+    # host/device overlap split (DESIGN.md §10):
     #   host_time        — scheduling, packing, metadata build, commit,
     #                      finalize (pure host work)
     #   dispatch_time    — time inside jitted calls (enqueue overhead on an
@@ -288,12 +279,6 @@ class _InFlight:
     iteration: int               # stats.iterations at launch
 
 
-def _to_token(v) -> int:
-    """Sampled array element -> token id (multi-codebook frontends keep
-    codebook 0 — the one rule, shared by every step path)."""
-    return int(v) if np.ndim(v) == 0 else int(v.flat[0])
-
-
 class ServeEngine:
     #: legacy keyword -> EngineConfig field (one release of back-compat;
     #: ``page_size`` is the old name for the block-table block size)
@@ -302,8 +287,7 @@ class ServeEngine:
         "page_size": "kv_block_size", "kv_block_size": "kv_block_size",
         "total_pages": "total_pages", "kv_budget_bytes": "kv_budget_bytes",
         "avg_decode_len": "avg_decode_len",
-        "discrete_sizes": "discrete_sizes", "prefill_mode": "prefill_mode",
-        "step_mode": "step_mode", "async_depth": "async_depth",
+        "discrete_sizes": "discrete_sizes", "async_depth": "async_depth",
         "async_harvest": "async_harvest", "nano": "nano", "tp": "tp",
         "kv_buckets": "kv_buckets", "kv_bucketing": "kv_bucketing",
         "prefix_caching": "prefix_caching", "attn_fast": "attn_fast",
@@ -341,8 +325,6 @@ class ServeEngine:
         self.params = params
         self.max_slots = config.max_slots
         self.max_len = config.max_len
-        self.prefill_mode = config.prefill_mode
-        self.step_mode = config.resolved_step_mode
         # async pipeline (DESIGN.md §10): up to async_depth iterations stay
         # in flight; async_harvest additionally retires any already-finished
         # iteration without blocking, shrinking the speculation window
@@ -477,8 +459,9 @@ class ServeEngine:
         # scheduler's worst-case KV accounting already covers
         self._pos = np.zeros((self.max_slots,), np.int64)
 
-        # fresh one-slot cache, scattered into a slot on (re)assignment so a
-        # reused slot never leaks the previous request's recurrent state
+        # fresh one-slot cache, selected into a slot's recurrent state on
+        # (re)assignment (``_reset_recurrent``) so a reused slot never leaks
+        # the previous request's state
         self._slot_init = self._new_cache(1)
 
         # modeled collective wire bytes per launched token (linear in T):
@@ -516,10 +499,6 @@ class ServeEngine:
         # program; the donated cache makes each copy a data dependency of
         # the following packed dispatch (device-order safety without a sync)
         self._cow_step = jax.jit(self._cow_impl, donate_argnums=(0,))
-        self._decode_step = jax.jit(self._decode_impl, donate_argnums=(1,))
-        # one compiled program per bucketed chunk length (scheduler-quantized)
-        self._prefill_step = jax.jit(self._prefill_impl, donate_argnums=(1,))
-        self._reset_step = jax.jit(_reset_slot, donate_argnums=(0,))
 
     def _new_cache(self, slots: int) -> list:
         if self._mesh is None:
@@ -527,51 +506,6 @@ class ServeEngine:
                                         self._cache_kv_dtype)
         return tp_lib.init_cache_tp(self.cfg, slots, self.max_len,
                                     self._mesh, self._cache_kv_dtype)
-
-    # ---- jitted decode over all slots (static shapes) -----------------------
-    def _decode_impl(self, params, cache, tokens, cache_len, active):
-        with ops.attn_config(fast=self.attn_fast, stream=self.attn_stream):
-            logits, new_cache = model_lib.forward_decode(
-                self.cfg, params, tokens, cache, cache_len)
-        next_tok = sampling.greedy(logits)
-        # Mask the *recurrent* state update to decoding slots: a mid-prefill
-        # slot's carried SSM/LSTM state must not be advanced by its garbage
-        # decode token.  Attention K/V leaves keep the donated in-place
-        # update: the garbage row lands at the slot's cache_len, which the
-        # next prefill chunk overwrites before attending — selecting the big
-        # seq-dim leaves would force a full cache copy per decode step.
-        def sel(n, o):
-            m = active.reshape((1, -1) + (1,) * (n.ndim - 2))
-            return jnp.where(m, n, o)
-        out = []
-        for gi, (pattern, reps) in enumerate(self.cfg.layer_groups()):
-            g = {}
-            for i, spec in enumerate(pattern):
-                n_sub = new_cache[gi][f"sub{i}"]
-                g[f"sub{i}"] = n_sub if spec.mixer == ATTN else jax.tree.map(
-                    sel, n_sub, cache[gi][f"sub{i}"])
-            out.append(g)
-        return next_tok, out
-
-    # ---- jitted incremental prefill chunk (one slot, bucketed length) -------
-    def _prefill_impl(self, params, cache, tokens, slot, offset):
-        """tokens: (1, L[, K]) — the next L prompt positions of ``slot``
-        after an ``offset``-token prefix.  Gathers the slot's sub-cache,
-        runs ``forward_chunk``, scatters the updated sub-cache back
-        (partial-prefix write at an arbitrary offset).  ``slot`` and
-        ``offset`` are traced, so one compiled program serves every slot and
-        prefix depth of a given chunk length."""
-        sub = jax.tree.map(
-            lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=1),
-            cache)
-        with ops.attn_config(fast=self.attn_fast, stream=self.attn_stream):
-            logits, new_sub = model_lib.forward_chunk(
-                self.cfg, params, tokens, sub, offset[None])
-        new_cache = jax.tree.map(
-            lambda c, s: jax.lax.dynamic_update_slice_in_dim(
-                c, s.astype(c.dtype), slot, axis=1),
-            cache, new_sub)
-        return sampling.greedy(logits[:, -1]), new_cache
 
     # ---- jitted token-packed step (one dispatch per iteration) --------------
     def _packed_impl(self, params, cache, tokens, token_slot, token_pos,
@@ -893,16 +827,7 @@ class ServeEngine:
         self.stats.iterations += 1
         self.stats.dense_batch_hist[plan.dense_batch] = \
             self.stats.dense_batch_hist.get(plan.dense_batch, 0) + 1
-        if self.step_mode != "packed":
-            self.scheduler.mark_launched(plan)
-            sampled = self._step_legacy(plan)
-            now = time.perf_counter()
-            finished = self.scheduler.commit(plan, sampled, self._clock())
-            for r in finished:
-                self._finalize(r)
-            self.stats.host_time += time.perf_counter() - now
-            return finished
-        # packed path: launch now, sync up to async_depth iterations later
+        # launch now, sync up to async_depth iterations later
         self._ring.append(self._launch_packed(plan))
         finished: list[Request] = []
         if self.async_harvest:
@@ -1157,123 +1082,6 @@ class ServeEngine:
         return _InFlight(plan=plan, sample_at=sample_at, tokens=payload,
                          iteration=it)
 
-    # ---- legacy iteration: decode dispatch + one dispatch per chunk ---------
-    def _step_legacy(self, plan: BatchPlan) -> dict[int, int]:
-        sampled: dict[int, int] = {}
-
-        # ---- batched decode over all slots (static shape) --------------------
-        decode_reqs = [r for r in plan.decode if r.slot >= 0]
-        if decode_reqs:
-            tokens = np.zeros((self.max_slots, 1), np.int32)
-            active = np.zeros((self.max_slots,), bool)
-            for r in decode_reqs:
-                tokens[r.slot, 0] = r.output[-1] if r.output else r.prompt[-1]
-                active[r.slot] = True
-            tok_in = jnp.asarray(tokens)
-            if self.cfg.frontend == "audio":
-                tok_in = jnp.repeat(tok_in[..., None], self.cfg.num_codebooks,
-                                    axis=-1)
-            t_disp = time.perf_counter()
-            next_tok, self.cache = self._decode_step(
-                self.params, self.cache, tok_in, self.cache_len,
-                jnp.asarray(active))
-            self.stats.dispatch_time += time.perf_counter() - t_disp
-            self.stats.model_dispatches += 1
-            self.cache_len = self.cache_len + jnp.asarray(active, jnp.int32)
-            nt = self._fetch(next_tok)
-            for r in decode_reqs:
-                sampled[r.rid] = _to_token(nt[r.slot])
-                self._pos[r.slot] += 1
-            self.stats.decode_tokens += len(decode_reqs)
-
-        # ---- chunked prefill -------------------------------------------------
-        for chunk in plan.prefill:
-            r = chunk.req
-            if r.slot < 0:
-                assert self.slot_free, "scheduler admitted beyond slot capacity"
-                r.slot = self.slot_free.pop()
-                self._pos[r.slot] = 0
-                if r.scheduled_at is None:
-                    r.scheduled_at = self._clock()
-                if self.prefill_mode == "incremental":
-                    self.cache = self._reset_step(
-                        self.cache, self._slot_init, jnp.int32(r.slot))
-                    self.stats.model_dispatches += 1
-            if self.prefill_mode == "incremental":
-                last_tok = self._prefill_chunk(r, chunk.offset, chunk.length)
-                self.stats.prefill_model_tokens += chunk.length
-            else:
-                last_tok = self._prefill_to(r, chunk.offset + chunk.length)
-                self.stats.prefill_model_tokens += chunk.offset + chunk.length
-            self.stats.prefill_tokens += chunk.length
-            self._pos[r.slot] = chunk.offset + chunk.length
-            if chunk.offset + chunk.length == r.prompt_len:
-                sampled[r.rid] = last_tok
-        return sampled
-
-    # ---- internals -----------------------------------------------------------
-    def _prefill_chunk(self, r: Request, offset: int, length: int) -> int:
-        """Incremental path: run exactly ``length`` new prompt tokens against
-        the slot's carried cache (O(length) model FLOPs)."""
-        toks = np.asarray(r.prompt[offset:offset + length], np.int32)[None]
-        tok_in = jnp.asarray(toks)
-        if self.cfg.frontend == "audio":
-            tok_in = jnp.repeat(tok_in[..., None], self.cfg.num_codebooks,
-                                axis=-1)
-        t_disp = time.perf_counter()
-        next_tok, self.cache = self._prefill_step(
-            self.params, self.cache, tok_in, jnp.int32(r.slot),
-            jnp.int32(offset))
-        self.stats.dispatch_time += time.perf_counter() - t_disp
-        self.stats.model_dispatches += 1
-        self.cache_len = self.cache_len.at[r.slot].set(offset + length)
-        return _to_token(self._fetch(next_tok))
-
-    def _prefill_to(self, r: Request, upto: int) -> int:
-        """Recompute path (``prefill_mode="recompute"``; pre-DESIGN.md-§7
-        behaviour, kept for A/B benchmarks): re-run ``forward_full`` over the
-        whole prefix [0, upto) and scatter its states into the request's
-        slot — O(p²/chunk) FLOPs per prompt, correct for every mixer
-        family."""
-        cfg = self.cfg
-        toks = np.asarray(r.prompt[:upto], np.int32)[None]
-        tok_in = jnp.asarray(toks)
-        if cfg.frontend == "audio":
-            tok_in = jnp.repeat(tok_in[..., None], cfg.num_codebooks, axis=-1)
-        t_disp = time.perf_counter()
-        with ops.attn_config(fast=self.attn_fast, stream=self.attn_stream):
-            logits, _aux, states = model_lib.forward_full(
-                cfg, self.params, tok_in, return_states=True)
-        self.stats.dispatch_time += time.perf_counter() - t_disp
-        self.stats.model_dispatches += 1
-        self._scatter_states(r.slot, states)
-        self.cache_len = self.cache_len.at[r.slot].set(upto)
-        last = self._fetch(logits[0, -1])
-        return _to_token(last.argmax(-1))
-
-    def _scatter_states(self, slot: int, states) -> None:
-        """Write per-layer mixer states into a slot (recompute path: the
-        whole prefix at offset 0).  The incremental path's partial-prefix
-        writes at arbitrary offsets happen inside the jitted
-        ``_prefill_impl`` via ``attention._write_seq_at``."""
-        for gi, (pattern, reps) in enumerate(self.cfg.layer_groups()):
-            for i, spec in enumerate(pattern):
-                st = states[gi][f"sub{i}"]
-                dst = self.cache[gi][f"sub{i}"]
-                if spec.mixer == ATTN:
-                    if self.cfg.mla is not None:
-                        ck, kr = st["kv"]
-                        dst["c_kv"] = _write_slot_seq(dst["c_kv"], ck, slot)
-                        dst["k_rope"] = _write_slot_seq(dst["k_rope"], kr,
-                                                        slot)
-                    else:
-                        k, v = st["kv"]
-                        dst["k"] = _write_slot_seq(dst["k"], k, slot)
-                        dst["v"] = _write_slot_seq(dst["v"], v, slot)
-                else:
-                    for name, val in st.items():
-                        dst[name] = _write_slot(dst[name], val, slot)
-
     def _finalize(self, r: Request) -> None:
         if r.slot >= 0:
             self.slot_free.append(r.slot)
@@ -1290,22 +1098,3 @@ class ServeEngine:
                         nbytes=max(r.total_tokens * self.kv.bytes_per_token,
                                    1))
 
-
-def _reset_slot(cache, init, slot):
-    """Scatter a fresh one-slot cache into ``slot`` of the full cache."""
-    return jax.tree.map(
-        lambda c, z: jax.lax.dynamic_update_slice_in_dim(
-            c, z.astype(c.dtype), slot, axis=1),
-        cache, init)
-
-
-def _write_slot_seq(cache: jax.Array, chunk: jax.Array, slot: int) -> jax.Array:
-    """cache: (L, B, S, ...); chunk: (L, 1, s, ...) -> rows [0, s) of slot."""
-    idx = (0, slot, 0) + (0,) * (cache.ndim - 3)
-    return jax.lax.dynamic_update_slice(cache, chunk.astype(cache.dtype), idx)
-
-
-def _write_slot(cache: jax.Array, state: jax.Array, slot: int) -> jax.Array:
-    """cache: (L, B, ...); state: (L, 1, ...) -> write slot row."""
-    idx = (0, slot) + (0,) * (cache.ndim - 2)
-    return jax.lax.dynamic_update_slice(cache, state.astype(cache.dtype), idx)

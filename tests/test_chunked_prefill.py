@@ -6,10 +6,10 @@ Covers the tentpole invariants:
     above the conv kernel;
   * the ``forward_full(initial_states=...)`` carry path matches too;
   * linear work: a p-token prompt prefilled in k chunks executes exactly p
-    model token-positions (the recompute path strictly more);
-  * the engine's jitted bucketed path: greedy-exact vs naive decoding on the
-    attention toy, mode-equivalent (incremental vs recompute) on recurrent /
-    MoE archs, through slot reuse;
+    model token-positions;
+  * the engine's packed step: greedy-exact vs naive decoding on the
+    attention toy, through slot reuse, eager and pipelined, with and without
+    prefix caching;
   * the scheduler only emits bucketed chunk lengths.
 """
 import dataclasses
@@ -22,6 +22,7 @@ import pytest
 from repro.configs import get_config, scale_down
 from repro.configs.base import ATTN
 from repro.models import model
+from repro.serving.config import EngineConfig
 from repro.serving.engine import ServeEngine
 from repro.serving.kvcache import PagedKVManager
 from repro.serving.request import Request
@@ -177,41 +178,33 @@ def test_engine_prefill_work_is_linear():
     assert st.iterations >= 8
 
 
-def test_recompute_mode_is_superlinear():
-    """The legacy recompute path documents the O(p²/chunk) behaviour the
-    incremental path removes."""
-    cfg = get_config("tiny-toy")
-    params = model.init(cfg, jax.random.PRNGKey(0))
-    rng = np.random.default_rng(0)
-    prompt = list(rng.integers(0, cfg.vocab_size, size=64))
-    eng = ServeEngine(cfg, params, max_slots=2, max_len=96,
-                      discrete_sizes=(16,), avg_decode_len=2,
-                      prefill_mode="recompute")
-    eng.submit(Request(rid=0, prompt=prompt, max_new_tokens=2))
-    eng.run()
-    st = eng.stats
-    assert st.prefill_tokens == 64
-    # 16+32+48+64 = 160 model token-positions for a 64-token prompt
-    assert st.prefill_model_tokens == 160
-    assert st.prefill_expansion > 1.0
-
-
-def test_engine_incremental_matches_naive_greedy():
+@pytest.mark.parametrize("prefix_caching", [False, True],
+                         ids=["no-prefix", "prefix"])
+@pytest.mark.parametrize("async_depth", [0, 1])
+def test_engine_incremental_matches_naive_greedy(async_depth, prefix_caching):
     """End-to-end: jitted bucketed chunked prefill + decode == token-by-token
-    full recomputation (attention toy; exact argmax equality)."""
+    full recomputation (attention toy; exact argmax equality), eager and
+    pipelined.  With prefix caching the prompts share a 20-token prefix, so
+    later requests attend a 16-token block an earlier request wrote."""
     cfg = get_config("tiny-toy")
     params = model.init(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     prompts = [list(rng.integers(0, cfg.vocab_size,
                                  size=int(rng.integers(3, 20))))
                for _ in range(5)]
-    eng = ServeEngine(cfg, params, max_slots=2, max_len=64,
-                      discrete_sizes=(8,), avg_decode_len=4)
+    if prefix_caching:
+        shared = list(rng.integers(0, cfg.vocab_size, size=20))
+        prompts = [shared + p for p in prompts]
+    eng = ServeEngine(cfg, params, EngineConfig(
+        max_slots=2, max_len=64, discrete_sizes=(8,), avg_decode_len=4,
+        async_depth=async_depth, prefix_caching=prefix_caching))
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, prompt=list(p), max_new_tokens=4))
     done = eng.run()
     assert len(done) == len(prompts)
     assert eng.stats.prefill_expansion == 1.0
+    if prefix_caching:
+        assert eng.kv.stats.prefix_hit_tokens > 0
     for r in done:
         toks = list(prompts[r.rid])
         want = []
@@ -222,36 +215,6 @@ def test_engine_incremental_matches_naive_greedy():
             want.append(t)
             toks.append(t)
         assert r.output == want, (r.rid, r.output, want)
-
-
-@pytest.mark.parametrize("arch", ["deepseek-v2-236b", "jamba-1.5-large-398b",
-                                  "xlstm-1.3b"])
-def test_engine_modes_agree_with_slot_reuse(arch):
-    """Incremental == recompute engine outputs on MLA/SSM/xLSTM archs, with
-    more requests than slots so slots get reused (state reset path).
-
-    Both modes pin ``step_mode="legacy"`` so this stays the §7 prefill A/B
-    it always was (recompute implies the legacy step; running incremental
-    through the packed step would compare different bf16 accumulation
-    orders instead — packed-vs-legacy equivalence is covered in f32 by
-    tests/test_packed_step.py)."""
-    cfg = _cfg(arch)
-    params = model.init(cfg, jax.random.PRNGKey(0))
-    rng = np.random.default_rng(1)
-    prompts = [list(rng.integers(0, cfg.vocab_size,
-                                 size=int(rng.integers(3, 12))))
-               for _ in range(5)]
-    outs = {}
-    for mode in ("incremental", "recompute"):
-        eng = ServeEngine(cfg, params, max_slots=2, max_len=48,
-                          discrete_sizes=(16, 8), avg_decode_len=4,
-                          prefill_mode=mode, step_mode="legacy")
-        for i, p in enumerate(prompts):
-            eng.submit(Request(rid=i, prompt=list(p), max_new_tokens=3))
-        done = eng.run()
-        assert len(done) == len(prompts)
-        outs[mode] = {r.rid: r.output for r in done}
-    assert outs["incremental"] == outs["recompute"]
 
 
 @pytest.mark.parametrize("variant", ["flash_attention_ref",

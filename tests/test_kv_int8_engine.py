@@ -206,9 +206,7 @@ def test_int8_composes_with_spec_decode():
     assert eng.stats.dispatches_per_iter == 1.0
 
 
-def test_int8_requires_packed_step():
-    with pytest.raises(AssertionError):
-        EngineConfig(kv_dtype="int8", step_mode="legacy")
+def test_int8_kv_dtype_validation():
     with pytest.raises(AssertionError):
         EngineConfig(kv_dtype="fp8")             # unknown dtype tag
     assert EngineConfig(kv_dtype="int8").kv_dtype == "int8"

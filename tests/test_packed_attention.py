@@ -11,9 +11,9 @@ Covers the tentpole invariants:
     ``d_v != d_qk`` case runs the Pallas kernel, no silent ref downgrade;
   * kv-bucket slicing is exact at and around bucket boundaries, in the ref,
     the kernel, and the scheduler's quantizer;
-  * engine end-to-end: kv-bucketed packed step == dense max_len sweep ==
-    legacy step (f32 per the known bf16-nondeterminism constraint), with a
-    request crossing a bucket edge mid-decode;
+  * engine end-to-end: kv-bucketed packed step == dense max_len sweep
+    (f32 per the known bf16-nondeterminism constraint), with a request
+    crossing a bucket edge mid-decode;
   * the packed compile cache is bounded by |T buckets| × |kv buckets| and
     the kv-bucket histogram records what launched;
   * the §Perf-HC3 env toggles are now explicit engine arguments (env is
@@ -410,11 +410,11 @@ def test_default_kv_buckets_grid():
 
 
 # ---------------------------------------------------------------------------
-# engine end-to-end: bucketed == dense == legacy (f32: bf16 accumulation-
-# order diffs + MoE routing would flip argmax between execution paths)
+# engine end-to-end: bucketed == dense (f32: bf16 accumulation-order diffs
+# + MoE routing would flip argmax between execution paths)
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("arch", ["tiny-toy", "deepseek-v2-236b"])
-def test_engine_kv_bucketing_matches_dense_and_legacy(arch):
+def test_engine_kv_bucketing_matches_dense(arch):
     cfg = get_config(arch) if arch == "tiny-toy" else scale_down(
         get_config(arch))
     if cfg.moe is not None:
@@ -429,8 +429,7 @@ def test_engine_kv_bucketing_matches_dense_and_legacy(arch):
                for n in (30, 12, 7)]
     outs = {}
     for name, kw in [("bucketed", dict(kv_buckets=(32, 64))),
-                     ("dense", dict(kv_bucketing=False)),
-                     ("legacy", dict(step_mode="legacy"))]:
+                     ("dense", dict(kv_bucketing=False))]:
         eng = ServeEngine(cfg, params, max_slots=2, max_len=64,
                           discrete_sizes=(16, 8), avg_decode_len=4, **kw)
         for i, p in enumerate(prompts):
@@ -442,7 +441,6 @@ def test_engine_kv_bucketing_matches_dense_and_legacy(arch):
             # both edge-straddling buckets really launched
             assert set(eng.stats.kv_bucket_hist) == {32, 64}
     assert outs["bucketed"] == outs["dense"]
-    assert outs["bucketed"] == outs["legacy"]
 
 
 def test_packed_compile_cache_bounded_by_t_times_kv_buckets():

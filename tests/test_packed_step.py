@@ -6,10 +6,11 @@ Covers the tentpole invariants:
     ``forward_chunk`` references, across every mixer family;
   * the stacked cache carried through the layer loop: each layer writes
     its own new rows and nothing else, contiguous and block-table;
-  * engine packed step == legacy per-chunk step end-to-end (f32 so op-order
-    rounding can't flip MoE routing), through slot reuse;
+  * engine packed step == token-by-token ``forward_full`` greedy decoding
+    end-to-end (f32 so op-order rounding can't flip MoE routing), through
+    slot reuse, across every mixer family;
   * exactly one jitted model dispatch and one device→host transfer per
-    engine iteration (the legacy step strictly more);
+    engine iteration;
   * the compile cache is bounded by the scheduler's discrete dense sizes
     and the launched shapes come from that set;
   * prefill expansion stays 1.0 and padding is accounted;
@@ -28,6 +29,7 @@ from repro.configs import get_config, scale_down
 from repro.configs.base import ATTN
 from repro.core.nanobatch import NanoBatchPlan, packed_segment_order
 from repro.models import model
+from repro.serving.config import EngineConfig
 from repro.serving.engine import ServeEngine
 from repro.serving.kvcache import PagedKVManager
 from repro.serving.request import Request
@@ -238,57 +240,59 @@ def test_forward_packed_writes_each_layers_new_rows_only(family, mode):
                                       what + (s,))
 
 
-def test_engine_packed_matches_legacy(family):
-    """End-to-end A/B: the packed single-dispatch step produces the same
-    tokens as the legacy decode-then-per-chunk step, with slot reuse."""
+def _naive_greedy(cfg, params, prompt, n):
+    """Token-by-token greedy decoding by full recomputation, fed and read by
+    the engine's rule for multi-codebook frontends: each token repeated
+    over the codebooks on input, codebook 0 kept on output."""
+    toks, out = list(prompt), []
+    for _ in range(n):
+        x = jnp.asarray(toks, jnp.int32)[None]
+        if cfg.frontend == "audio":
+            x = jnp.repeat(x[..., None], cfg.num_codebooks, axis=-1)
+        logits, _ = model.forward_full(cfg, params, x)
+        t = int(np.asarray(jnp.argmax(logits[0, -1], axis=-1)).flat[0])
+        out.append(t)
+        toks.append(t)
+    return out
+
+
+def test_engine_packed_matches_naive_greedy(family):
+    """End-to-end: the packed single-dispatch step produces the same tokens
+    as token-by-token ``forward_full`` greedy decoding, with slot reuse
+    (5 requests through 2 slots)."""
     cfg, params = family
     rng = np.random.default_rng(1)
     prompts = [list(rng.integers(0, cfg.vocab_size,
                                  size=int(rng.integers(3, 12))))
                for _ in range(5)]
-    outs = {}
-    for mode in ("packed", "legacy"):
-        eng = ServeEngine(cfg, params, max_slots=2, max_len=48,
-                          discrete_sizes=(16, 8), avg_decode_len=4,
-                          step_mode=mode)
-        for i, p in enumerate(prompts):
-            eng.submit(Request(rid=i, prompt=list(p), max_new_tokens=3))
-        done = eng.run()
-        assert len(done) == len(prompts)
-        outs[mode] = {r.rid: r.output for r in done}
-    assert outs["packed"] == outs["legacy"]
+    eng = ServeEngine(cfg, params, EngineConfig(
+        max_slots=2, max_len=48, discrete_sizes=(16, 8), avg_decode_len=4))
+    for i, p in enumerate(prompts):
+        eng.submit(Request(rid=i, prompt=list(p), max_new_tokens=3))
+    done = eng.run()
+    assert len(done) == len(prompts)
+    for r in done:
+        want = _naive_greedy(cfg, params, prompts[r.rid], r.max_new_tokens)
+        assert r.output == want, (cfg.name, r.rid, r.output, want)
 
 
 def test_packed_one_dispatch_one_sync_per_iteration():
     """Acceptance criterion: a packed iteration issues exactly one jitted
-    model dispatch and one device→host transfer; the legacy step issues
-    1 + K dispatches (decode + per-chunk) with a blocking sync per chunk."""
+    model dispatch and one device→host transfer."""
     cfg = get_config("tiny-toy")
     params = model.init(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
-
-    def load(eng):
-        for i in range(6):
-            eng.submit(Request(
-                rid=i, prompt=list(rng.integers(0, cfg.vocab_size, size=20)),
-                max_new_tokens=4))
-        eng.run()
-
-    eng = ServeEngine(cfg, params, max_slots=4, max_len=64,
-                      discrete_sizes=(32, 16, 8), avg_decode_len=4,
-                      step_mode="packed")
-    load(eng)
+    eng = ServeEngine(cfg, params, EngineConfig(
+        max_slots=4, max_len=64, discrete_sizes=(32, 16, 8),
+        avg_decode_len=4))
+    for i in range(6):
+        eng.submit(Request(
+            rid=i, prompt=list(rng.integers(0, cfg.vocab_size, size=20)),
+            max_new_tokens=4))
+    eng.run()
     assert eng.stats.iterations > 0
     assert eng.stats.model_dispatches == eng.stats.iterations
     assert eng.stats.host_syncs == eng.stats.iterations
-
-    rng = np.random.default_rng(0)
-    leg = ServeEngine(cfg, params, max_slots=4, max_len=64,
-                      discrete_sizes=(32, 16, 8), avg_decode_len=4,
-                      step_mode="legacy")
-    load(leg)
-    assert leg.stats.model_dispatches > leg.stats.iterations
-    assert leg.stats.host_syncs > leg.stats.iterations
 
 
 def test_packed_compile_cache_bounded_and_shapes_discrete():
@@ -299,8 +303,7 @@ def test_packed_compile_cache_bounded_and_shapes_discrete():
     params = model.init(cfg, jax.random.PRNGKey(0))
     sizes = (32, 16, 8)
     eng = ServeEngine(cfg, params, max_slots=4, max_len=64,
-                      discrete_sizes=sizes, avg_decode_len=4,
-                      step_mode="packed")
+                      discrete_sizes=sizes, avg_decode_len=4)
     rng = np.random.default_rng(2)
     for i in range(8):
         eng.submit(Request(
@@ -316,17 +319,6 @@ def test_packed_compile_cache_bounded_and_shapes_discrete():
     # padding accounted on both sides of the scheduler/engine boundary
     assert eng.stats.packed_pad_tokens == eng.scheduler.padding_tokens
     assert eng.scheduler.launched_tokens >= eng.stats.total_tokens
-
-
-def test_step_mode_validation():
-    cfg = get_config("tiny-toy")
-    params = model.init(cfg, jax.random.PRNGKey(0))
-    with pytest.raises(AssertionError):
-        ServeEngine(cfg, params, step_mode="packed",
-                    prefill_mode="recompute")
-    eng = ServeEngine(cfg, params, prefill_mode="recompute")
-    assert eng.step_mode == "legacy"          # auto-fallback
-    assert ServeEngine(cfg, params).step_mode == "packed"
 
 
 # ---------------------------------------------------------------------------

@@ -253,18 +253,11 @@ def test_prefix_engine_token_exact_vs_no_sharing(family, depth):
 # ---------------------------------------------------------------------------
 def test_engine_config_validation():
     with pytest.raises(AssertionError):
-        EngineConfig(step_mode="packed", prefill_mode="recompute")
-    with pytest.raises(AssertionError):
-        EngineConfig(tp=2, step_mode="legacy")
-    with pytest.raises(AssertionError):
-        EngineConfig(prefix_caching=True, step_mode="legacy")
-    with pytest.raises(AssertionError):
         EngineConfig(prefix_caching=True, max_len=60, kv_block_size=16)
     # defaulting rules stay un-baked: replace() re-resolves
     c = EngineConfig()
-    assert c.resolved_step_mode == "packed" and c.resolved_async_depth == 1
-    c2 = dataclasses.replace(c, prefill_mode="recompute", step_mode="legacy")
-    assert c2.resolved_step_mode == "legacy" and c2.resolved_async_depth == 0
+    assert c.async_depth is None and c.resolved_async_depth == 1
+    assert dataclasses.replace(c, async_depth=0).resolved_async_depth == 0
 
 
 def test_engine_config_from_args_and_overrides():

@@ -215,8 +215,6 @@ def test_stochastic_spec_decode_token_exact():
 def test_config_validation():
     with pytest.raises(AssertionError):
         EngineConfig(spec_k=-1)
-    with pytest.raises(AssertionError, match="packed"):
-        EngineConfig(step_mode="legacy", spec_k=2)
     with pytest.raises(AssertionError):
         EngineConfig(max_len=8, spec_k=8)
     with pytest.raises(AssertionError):
