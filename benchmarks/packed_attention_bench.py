@@ -18,9 +18,10 @@ orders of magnitude slower than compiled code; it is for correctness
 spot-checks, so the default impl here is ``ref``.
 
 ``--launches`` prints, for the launch shapes of the Qwen3-4B offline cell
-(``launches``), the KV block DMAs of the tiled kernel against the
-per-token grid it replaced, and on a TPU the device time of one call at
-Qwen3-4B widths for each ``--block-q``.
+(``launches``), the KV block DMAs of the tiled kernel (all kv heads per
+DMA) against the per-token grid it replaced (one kv head per DMA), and on
+a TPU the device time of one call at Qwen3-4B widths for each
+``--block-q``.
 """
 from __future__ import annotations
 
@@ -52,12 +53,13 @@ def cost_model(t: int, h: int, kv: int, d_qk: int, d_v: int, n_slots: int,
     av_flops = 2 * t * h * s * d_v           # context
     cache_row = kv * (d_qk + d_v) * itemsize
     blocks = pa.kv_blocks_fetched(token_slot, lengths, n_slots=n_slots,
-                                  kv_heads=kv, group=h // kv, kv_bucket=s)
+                                  group=h // kv, kv_bucket=s)
     block_rows = min(pa.DEFAULT_BLOCK_K, max(8, s))
     return {
         "gather_flops": qk_flops + av_flops,
-        # the KV blocks the tile plan fetches; q + out are T×H vectors
-        "gather_bytes": (blocks * block_rows * (d_qk + d_v) * itemsize
+        # the KV blocks (every kv head's rows) the tile plan fetches; q +
+        # out are T×H vectors
+        "gather_bytes": (blocks * block_rows * cache_row
                          + t * h * (d_qk + d_v) * itemsize),
         "dense_flops": n_slots * (qk_flops + av_flops),
         # caches read once; the (T, N, KV, G, S) score tensor round-trips
@@ -133,8 +135,8 @@ def _kernel_seconds(t: int, bucket: int, slot, lens, block_q: int,
                     reps: int = 20) -> float:
     """Seconds of one call at the cell's widths: ``reps`` calls chained in
     one program (each q depends on the last output), over ``reps``.  The
-    tile plan and the q/output permutations are inside the chain; the K/V
-    transpose is loop-invariant there, so XLA may hoist it out."""
+    tile plan and the q/output permutations are inside the chain; the
+    kernel reads the K/V caches in place."""
     c = CELL
     rng = np.random.default_rng(1)
     q = jnp.asarray(rng.normal(size=(t, c["h"], c["d"])), jnp.bfloat16)
@@ -155,8 +157,9 @@ def _kernel_seconds(t: int, bucket: int, slot, lens, block_q: int,
 
 
 def launch_rows(block_qs: list[int]) -> list[dict]:
-    """KV block DMAs per call of each cell launch, tiled against per-token;
-    on a TPU also the device time per call at each ``block_q``."""
+    """KV block DMAs per call of each cell launch, tiled (each DMA every kv
+    head's rows of a block) against per-token (each one kv head's); on a
+    TPU also the device time per call at each ``block_q``."""
     c = CELL
     on_chip = jax.default_backend() == "tpu"
     rows = []
@@ -166,8 +169,8 @@ def launch_rows(block_qs: list[int]) -> list[dict]:
             t, kv_heads=c["kv"], kv_bucket=bucket)
         for bq in block_qs:
             tiled = pa.kv_blocks_fetched(
-                slot, lens, n_slots=c["n_slots"], kv_heads=c["kv"],
-                group=c["h"] // c["kv"], kv_bucket=bucket, block_q=bq)
+                slot, lens, n_slots=c["n_slots"], group=c["h"] // c["kv"],
+                kv_bucket=bucket, block_q=bq)
             row = {"bench": "packed_attention_launch",
                    "case": f"{name}/kv{bucket}/Bq{bq}", "block_q": bq,
                    "kv_blocks_tiled": tiled,

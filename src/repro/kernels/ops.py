@@ -138,11 +138,17 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, logit_scale=None,
 
 
 def packed_attention(q, k_cache, v_cache, token_slot, lengths, *,
-                     logit_scale=None, kv_bucket: Optional[int] = None,
+                     layer=0, logit_scale=None,
+                     kv_bucket: Optional[int] = None,
                      block_tables=None, k_scale=None, v_scale=None,
                      impl: Optional[str] = None, fast: Optional[bool] = None):
     """Segment-masked attention over a token-packed stream (DESIGN.md §8):
     token t attends rows [0, lengths[t]) of slot ``token_slot[t]``'s cache.
+
+    The caches are either one layer's ``(N_slots, S, KV, D)`` or the
+    model step's stacked ``(L, N_slots, S, KV, D)``, of which layer
+    ``layer`` (traced) is read: the Pallas kernel reads it in place, the
+    refs cut the layer's view first.
 
     ``kv_bucket`` (static) bounds the swept cache extent — the engine passes
     the iteration's KV-length bucket so work scales with actual context, not
@@ -155,9 +161,9 @@ def packed_attention(q, k_cache, v_cache, token_slot, lengths, *,
     the per-slot table (index-map dereference in the Pallas kernel, dense
     per-slot gather in the refs).
 
-    ``k_scale``/``v_scale`` (optional, (N_slots, S, KV) f32, DESIGN.md §15):
-    int8 caches — every impl dequantizes after the int8 read (in-register
-    in the Pallas kernel, dense in the refs)."""
+    ``k_scale``/``v_scale`` (optional, f32, the caches' shape without the
+    head dim, DESIGN.md §15): int8 caches — every impl dequantizes after
+    the int8 read (in-register in the Pallas kernel, dense in the refs)."""
     impl = _resolve(impl)
     if impl == "ref":
         fn = _ref.packed_attention_fast if _attn_fast(fast) \
@@ -165,10 +171,11 @@ def packed_attention(q, k_cache, v_cache, token_slot, lengths, *,
         return fn(q, k_cache, v_cache, token_slot, lengths,
                   logit_scale=logit_scale, kv_bucket=kv_bucket,
                   block_tables=block_tables, k_scale=k_scale,
-                  v_scale=v_scale)
+                  v_scale=v_scale, layer=layer)
     from repro.kernels import packed_attention as _pa
     return _pa.packed_attention(q, k_cache, v_cache, token_slot, lengths,
-                                logit_scale=logit_scale, kv_bucket=kv_bucket,
+                                layer=layer, logit_scale=logit_scale,
+                                kv_bucket=kv_bucket,
                                 block_tables=block_tables, k_scale=k_scale,
                                 v_scale=v_scale,
                                 interpret=(impl == "interpret"))

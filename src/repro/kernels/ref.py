@@ -115,10 +115,28 @@ def _block_gather_view(cache: jax.Array, block_tables: jax.Array,
     return view.reshape((n, nbk * bs) + cache.shape[2:])
 
 
+def _layer_view(stack: jax.Array, layer, rows: int) -> jax.Array:
+    """Layer ``layer``'s first ``rows`` rows of every slot of a stacked
+    cache leaf (L, N_slots, S, ...), as (N_slots, rows, ...)."""
+    return jax.lax.dynamic_slice(
+        stack, (layer,) + (0,) * (stack.ndim - 1),
+        (1, stack.shape[1], rows) + stack.shape[3:])[0]
+
+
 def _dequant_views(k_cache, v_cache, k_scale, v_scale, block_tables,
-                   kv_bucket):
-    """Apply the bucket / block-table view to the caches (and scale leaves,
-    when quantized), then dequantize to f32 for the dense sweep."""
+                   kv_bucket, layer):
+    """Cut layer ``layer`` out of stacked caches (5-D k/v), apply the
+    bucket / block-table view to the caches (and scale leaves, when
+    quantized), then dequantize to f32 for the dense sweep."""
+    if k_cache.ndim == 5:
+        s = k_cache.shape[2]
+        rows = s if block_tables is not None or kv_bucket is None \
+            else min(kv_bucket, s)
+        k_cache, v_cache = (_layer_view(x, layer, rows)
+                            for x in (k_cache, v_cache))
+        if k_scale is not None:
+            k_scale, v_scale = (_layer_view(x, layer, rows)
+                                for x in (k_scale, v_scale))
     if block_tables is not None:
         k_cache = _block_gather_view(k_cache, block_tables, kv_bucket)
         v_cache = _block_gather_view(v_cache, block_tables, kv_bucket)
@@ -141,16 +159,19 @@ def packed_attention_ref(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                          kv_bucket: Optional[int] = None,
                          block_tables: Optional[jax.Array] = None,
                          k_scale: Optional[jax.Array] = None,
-                         v_scale: Optional[jax.Array] = None
+                         v_scale: Optional[jax.Array] = None,
+                         layer=0
                          ) -> jax.Array:
     """Segment-masked attention for the token-packed dense-batch step
     (DESIGN.md §8): every token of a packed ``(T,)`` stream attends its own
     slot's cache rows ``[0, lengths[t])`` and nothing else.
 
     q: (T, H, D) packed queries; k_cache/v_cache: (N_slots, S, KV, D/Dv)
-    slot caches (the packed step scatters each token's K/V at its
-    ``(slot, position)`` before calling this); token_slot: (T,) int32 slot
-    per token; lengths: (T,) int32 = position + 1 per token; kv_bucket:
+    slot caches, or the stacked (L, N_slots, S, KV, D/Dv) caches of which
+    layer ``layer`` (traced) is read (the packed step scatters each
+    token's K/V at its ``(slot, position)`` before calling this);
+    token_slot: (T,) int32 slot per token; lengths: (T,) int32 =
+    position + 1 per token; kv_bucket:
     static bound on ``max(lengths)`` — only that many cache rows are read
     (KV-length bucketing, DESIGN.md §9), ``None`` means the full cache.
 
@@ -170,13 +191,14 @@ def packed_attention_ref(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     caches are physical block storage and each slot's logical rows are
     gathered through its table before the dense sweep.
 
-    ``k_scale``/``v_scale`` (optional, (N_slots, S, KV) f32, DESIGN.md §15):
+    ``k_scale``/``v_scale`` (optional, (N_slots, S, KV) f32, or stacked
+    like the caches, DESIGN.md §15):
     int8 caches — the same views apply to the scale leaves and the dense
     sweep dequantizes (``row * scale`` in f32) before the einsums; this is
     the XLA analogue of the Pallas kernel's in-register dequant.
     """
     k_cache, v_cache = _dequant_views(
-        k_cache, v_cache, k_scale, v_scale, block_tables, kv_bucket)
+        k_cache, v_cache, k_scale, v_scale, block_tables, kv_bucket, layer)
     t, h, d = q.shape
     n, s, kv, _ = k_cache.shape
     dv = v_cache.shape[-1]
@@ -203,13 +225,14 @@ def packed_attention_fast(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                           kv_bucket: Optional[int] = None,
                           block_tables: Optional[jax.Array] = None,
                           k_scale: Optional[jax.Array] = None,
-                          v_scale: Optional[jax.Array] = None
+                          v_scale: Optional[jax.Array] = None,
+                          layer=0
                           ) -> jax.Array:
     """No-upcast variant of ``packed_attention_ref`` (§Perf HC3): same
     math, bf16 einsum operands with f32 in-register accumulation (int8
     caches dequantize to f32 first — the scale multiply *is* the upcast)."""
     k_cache, v_cache = _dequant_views(
-        k_cache, v_cache, k_scale, v_scale, block_tables, kv_bucket)
+        k_cache, v_cache, k_scale, v_scale, block_tables, kv_bucket, layer)
     t, h, d = q.shape
     n, s, kv, _ = k_cache.shape
     dv = v_cache.shape[-1]

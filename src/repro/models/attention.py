@@ -173,39 +173,49 @@ def gqa_prefill_chunk(cfg: ModelConfig, p: dict, x: jax.Array,
     return y, {"k": k_cache, "v": v_cache}
 
 
-def _write_and_view(cache: dict, layer: jax.Array, new_rows: dict,
-                    token_slot: jax.Array, token_wpos: jax.Array,
-                    kv_bucket: Optional[int], token_dst, block_tables):
+def _write_rows(cache: dict, layer: jax.Array, new_rows: dict,
+                token_slot: jax.Array, token_wpos: jax.Array, token_dst,
+                block_tables) -> dict:
     """Scatter one layer's T new rows per leaf into the group's stacked
     cache (L, N_slots, S, ...), carried through the layer loop (DESIGN.md
     §8): at ``(layer, slot, wpos)``, or in block-table mode (DESIGN.md §12)
     at ``(layer, token_dst)`` of the (L, N·S, ...) view.  Padding rows
     carry an out-of-bounds ``wpos``/``dst`` and are dropped; only the T
-    rows move, the stack is updated in place.  Then cut the rows attention
-    reads from layer ``layer``: the first ``kv_bucket`` rows of each slot,
-    or in block-table mode the layer's whole storage (any block may be in
-    a slot's table).  Returns (new stacked cache, per-layer views)."""
-    new_cache, views = {}, {}
+    rows move, the stack is updated in place.  Returns the new stack; GQA
+    hands it to the kernel as it is, MLA cuts its layer's view from it
+    (``_layer_views``)."""
+    new_cache = {}
     for name, val in new_rows.items():
         stack = cache[name]
-        n_layers, n, s = stack.shape[:3]
         val = val.astype(stack.dtype)
         if block_tables is None:
             stack = stack.at[layer, token_slot, token_wpos].set(
                 val, mode="drop")
-            rows = s if kv_bucket is None else min(kv_bucket, s)
         else:
+            n_layers, n, s = stack.shape[:3]
             flat = stack.reshape((n_layers, n * s) + stack.shape[3:])
             stack = flat.at[layer, token_dst].set(
                 val, mode="drop").reshape(stack.shape)
-            rows = s
         new_cache[name] = stack
-        # one dynamic slice of the stack: indexing the layer first and
-        # cutting its rows afterwards makes XLA transpose all S rows
+    return new_cache
+
+
+def _layer_views(cache: dict, layer: jax.Array, kv_bucket: Optional[int],
+                 block_tables) -> dict:
+    """The rows of layer ``layer`` that MLA's absorbed concat reads, one
+    dynamic slice of each stacked leaf: the first ``kv_bucket`` rows of
+    each slot, or in block-table mode the layer's whole storage (any
+    block may be in a slot's table).  Indexing the layer first and cutting
+    its rows afterwards makes XLA transpose all S rows."""
+    views = {}
+    for name, stack in cache.items():
+        n, s = stack.shape[1:3]
+        rows = s if kv_bucket is None or block_tables is not None \
+            else min(kv_bucket, s)
         views[name] = jax.lax.dynamic_slice(
             stack, (layer,) + (0,) * (stack.ndim - 1),
             (1, n, rows) + stack.shape[3:])[0]
-    return new_cache, views
+    return views
 
 
 def _block_view(cache: jax.Array, block_tables: jax.Array,
@@ -242,7 +252,9 @@ def gqa_packed(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
     ``kv_bucket`` (static, DESIGN.md §9): the engine's bound on this
     iteration's ``max(positions) + 1`` — attention reads only that many
     cache rows per slot, so its FLOPs/bytes scale with actual context, not
-    ``max_len``.  The layer's rows are cut from the stack at that length.
+    ``max_len``.  The updated stack goes to the kernel whole, with
+    ``layer``: it reads that layer's rows in place, with no per-layer copy
+    or transpose of the cache in between (DESIGN.md §9).
 
     Under tensor parallelism (DESIGN.md §11) the projections and the slot
     cache are sharded along (kv-)heads, attention is per-head local, and
@@ -252,9 +264,8 @@ def gqa_packed(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
     Block-table mode (DESIGN.md §12, ``token_dst``/``block_tables`` set):
     the same leaves are treated as physical block storage — K/V scatter at
     flat row ``token_dst[t]`` of the layer and attention gathers through
-    the per-slot table, so requests can share immutable prefix blocks; it
-    is handed the layer's whole storage, since any block may be in a
-    table.  TP-safe: both reshapes fold the unsharded (slot, seq) axes only.
+    the per-slot table, so requests can share immutable prefix blocks.
+    TP-safe: the reshapes fold the unsharded (slot, seq) axes only.
 
     int8 KV (DESIGN.md §15, scale leaves ``k_s``/``v_s`` present): each
     token's post-rope K/V row quantizes *in-program* at scatter time
@@ -269,19 +280,15 @@ def gqa_packed(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
         new_rows = {"k": k_val, "v": v_val, "k_s": k_s_new, "v_s": v_s_new}
     else:
         new_rows = {"k": k_new[0], "v": v_new[0]}
-    new_cache, view = _write_and_view(cache, layer, new_rows, token_slot,
-                                      token_wpos, kv_bucket, token_dst,
-                                      block_tables)
-    k_cache = shard(view["k"], "batch", "kv_seq", "act_kv_heads", None)
-    v_cache = shard(view["v"], "batch", "kv_seq", "act_kv_heads", None)
-    k_scale = v_scale = None
+    new_cache = _write_rows(cache, layer, new_rows, token_slot, token_wpos,
+                            token_dst, block_tables)
+    scales = {}
     if quantized:
-        k_scale = shard(view["k_s"], "batch", "kv_seq", "act_kv_heads")
-        v_scale = shard(view["v_s"], "batch", "kv_seq", "act_kv_heads")
-    out = ops.packed_attention(q[0], k_cache, v_cache, token_slot,
-                               positions[0] + 1, kv_bucket=kv_bucket,
-                               block_tables=block_tables,
-                               k_scale=k_scale, v_scale=v_scale)
+        scales = {"k_scale": new_cache["k_s"], "v_scale": new_cache["v_s"]}
+    out = ops.packed_attention(q[0], new_cache["k"], new_cache["v"],
+                               token_slot, positions[0] + 1, layer=layer,
+                               kv_bucket=kv_bucket,
+                               block_tables=block_tables, **scales)
     y = tp.out_project(out, p["wo"])[None]
     y = shard(y, "batch", "act_seq", "embed")
     return y, new_cache
@@ -511,9 +518,9 @@ def mla_packed(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
                     "c_kv_s": c_s_new, "k_rope_s": r_s_new}
     else:
         new_rows = {"c_kv": c_new[0], "k_rope": r_new[0]}
-    new_cache, view = _write_and_view(cache, layer, new_rows, token_slot,
-                                      token_wpos, kv_bucket, token_dst,
-                                      block_tables)
+    new_cache = _write_rows(cache, layer, new_rows, token_slot, token_wpos,
+                            token_dst, block_tables)
+    view = _layer_views(new_cache, layer, kv_bucket, block_tables)
     view["c_kv"] = shard(view["c_kv"], "batch", "kv_seq", None)
     if block_tables is not None:
         view = {name: _block_view(a, block_tables, kv_bucket)

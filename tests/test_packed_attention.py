@@ -189,6 +189,8 @@ def _quantize(k, v):
     ("engine-bq8", 3, 64, 4, 2, 32, 32),
     ("shuffled-bq8-bt8", 3, 64, 4, 2, 32, 32),  # int8 + block table
     ("runs-bq4", 6, 48, 4, 1, 24, 16),          # absorbed MLA
+    (10, 3, 64, 8, 4, 32, 32),       # 4 kv heads: one int8 of each word
+    ("engine-bq8", 3, 64, 16, 8, 32, 32),       # 8: every other word
 ])
 def test_packed_attention_int8_parity(t, n, s, h, kv, d_qk, d_v):
     """Pallas kernel with int8 k/v + f32 scale tiles == ref dequant path
@@ -259,6 +261,70 @@ def test_packed_attention_int8_block_tables():
 
 
 # ---------------------------------------------------------------------------
+# the model step's stacked cache, read in place (DESIGN.md §8-§9)
+# ---------------------------------------------------------------------------
+# name -> (int8 KV, block-table block size, kv_bucket); block_k is 16
+STACKED = {
+    "contiguous": (False, None, 64),
+    "contiguous-ragged-bucket": (False, None, 40),   # not a multiple of 16
+    "int8": (True, None, 64),
+    "block-table": (False, 8, 48),
+    "int8-block-table": (True, 8, 64),
+}
+
+
+def _stack(leaf, poison):
+    """(3, ...) stack with ``leaf`` as layer 1 and ``poison`` elsewhere."""
+    other = jnp.full_like(leaf, poison)
+    return jnp.stack([other, leaf, other])
+
+
+@pytest.mark.parametrize("variant", list(STACKED))
+def test_packed_attention_reads_one_layer_of_the_stack(variant):
+    """An (L=3, N, S, KV, D) stack read at layer 1 (traced) equals the ref
+    on layer 1's 4-D slice.  Layers 0 and 2 are NaN (int8 leaves: NaN
+    scales), and so are layer 1's rows past a contiguous bucket: a read of
+    any of them would reach the output."""
+    int8, bs, bucket = STACKED[variant]
+    t, n, s, h, kv, d = 12, 3, 64, 4, 2, 16
+    q, k, v, slot, _ = _case(t, n, s, h, kv, d, d, jnp.float32)
+    lens = jnp.asarray(RNG.integers(1, bucket + 1, size=t), jnp.int32)
+    leaves = list(_quantize(k, v)) if int8 else [k, v]   # k, [k_s,] v[, v_s]
+    kw = {}
+    if bs is not None:
+        kw["block_tables"], *leaves = _to_blocks(n, s, bs, *leaves)
+    if int8:
+        kq, ks, vq, vs = leaves
+        want = ref.packed_attention_ref(q, kq, vq, slot, lens, k_scale=ks,
+                                        v_scale=vs, kv_bucket=bucket, **kw)
+        stacks = [_stack(kq, 0), _stack(ks, np.nan), _stack(vq, 0),
+                  _stack(vs, np.nan)]
+    else:
+        want = ref.packed_attention_ref(q, *leaves, slot, lens,
+                                        kv_bucket=bucket, **kw)
+        stacks = [_stack(x, np.nan) for x in leaves]
+    if bs is None:                       # poison layer 1 past the bucket
+        stacks = [x.at[1, :, bucket:].set(np.nan)
+                  if x.dtype == jnp.float32 else x for x in stacks]
+    if int8:
+        k_st, ks_st, v_st, vs_st = stacks
+        kw.update(k_scale=ks_st, v_scale=vs_st)
+    else:
+        k_st, v_st = stacks
+    layer = jnp.int32(1)
+    got = pa.packed_attention(q, k_st, v_st, slot, lens, layer=layer,
+                              kv_bucket=bucket, block_k=16, interpret=True,
+                              **kw)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=2e-5)
+    got_ref = ops.packed_attention(q, k_st, v_st, slot, lens, layer=layer,
+                                   kv_bucket=bucket, impl="ref", **kw)
+    np.testing.assert_allclose(np.asarray(got_ref), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
 # pad-free ragged last block (DESIGN.md §15): s % block_k != 0 masks the
 # final tile in-kernel instead of jnp.pad-ing a copy of the whole cache
 # ---------------------------------------------------------------------------
@@ -300,7 +366,7 @@ def test_packed_attention_no_cache_pad(monkeypatch):
 # the tile plan's KV block DMAs: the numpy count == a host replay of the
 # kernel's own tile plan and clamped index map
 # ---------------------------------------------------------------------------
-def _replayed_kv_dmas(slot, lens, *, n, kv, group, bucket, block_q):
+def _replayed_kv_dmas(slot, lens, *, n, group, bucket, block_q):
     """Consecutive distinct K block indices over the grid, in grid order,
     from ``_tile_plan`` and ``_kv_index`` as the kernel runs them."""
     t = len(slot)
@@ -313,12 +379,10 @@ def _replayed_kv_dmas(slot, lens, *, n, kv, group, bucket, block_q):
     tables = [np.asarray(x) for x in (tile_slot, tile_len, tile_hi)]
     prev, dmas = None, 0
     for i in range(n_tiles):
-        for head in range(kv):
-            for sb in range(-(-bucket // block_k)):
-                idx = tuple(int(x) for x in pa._kv_index(
-                    i, head, sb, *tables, kv_heads=kv))
-                dmas += idx != prev
-                prev = idx
+        for sb in range(-(-bucket // block_k)):
+            idx = tuple(int(x) for x in pa._kv_index(i, sb, *tables))
+            dmas += idx != prev
+            prev = idx
     return dmas
 
 
@@ -331,31 +395,34 @@ def _cell_launch():
     return slot, lens
 
 
+# ``kv`` heads ride every block DMA together, so they do not enter the count
 @pytest.mark.parametrize("launch,n,s,kv,group,block_q", [
     ("engine", 3, 64, 2, 4, 8),
     ("shuffled", 5, 96, 2, 2, 4),
-    ("runs", 6, 64, 1, 8, 4),        # one kv head: tiles share blocks
+    ("runs", 6, 64, 1, 8, 4),        # one-token runs, one tile per slot
     ("engine", 4, 64, 2, 4, 64),     # T < Bq
     ("cell", 8, 2048, 8, 4, pa.DEFAULT_BLOCK_Q),
 ])
 def test_kv_blocks_fetched_matches_index_map_replay(launch, n, s, kv, group,
                                                     block_q):
     slot, lens = _cell_launch() if launch == "cell" else _launch(launch, n, s)
-    want = _replayed_kv_dmas(slot, lens, n=n, kv=kv, group=group, bucket=s,
+    want = _replayed_kv_dmas(slot, lens, n=n, group=group, bucket=s,
                              block_q=block_q)
-    got = pa.kv_blocks_fetched(slot, lens, n_slots=n, kv_heads=kv,
-                               group=group, kv_bucket=s, block_q=block_q)
+    got = pa.kv_blocks_fetched(slot, lens, n_slots=n, group=group,
+                               kv_bucket=s, block_q=block_q)
     assert got == want
 
 
 def test_kv_blocks_fetched_cell_launch_10x_below_per_token():
+    """In one kv head's blocks: each tiled DMA carries all 8 heads, each
+    per-token DMA one."""
     slot, lens = _cell_launch()
-    tiled = pa.kv_blocks_fetched(slot, lens, n_slots=8, kv_heads=8, group=4,
+    tiled = pa.kv_blocks_fetched(slot, lens, n_slots=8, group=4,
                                  kv_bucket=2048)
     per_token = pa.kv_blocks_fetched_per_token(256, kv_heads=8,
                                                kv_bucket=2048)
     assert per_token == 256 * 8 * 8
-    assert 10 * tiled <= per_token, (tiled, per_token)
+    assert 10 * 8 * tiled <= per_token, (tiled, per_token)
 
 
 # ---------------------------------------------------------------------------

@@ -67,36 +67,48 @@ def _compile(fn, *args, donate=()):
     return compiled
 
 
-# (name, T, H, KV, Dqk, Dv, kv_bucket, int8, block-table block size)
+# (name, T, H, KV, Dqk, Dv, kv_bucket, int8, block-table block size,
+#  layers of a stacked cache read at a traced layer, or None for 4-D)
 KERNELS = [
-    ("bf16", 256, 32, 8, 128, 128, 1024, False, None),
-    ("bf16-ragged-bucket", 256, 32, 8, 128, 128, 1000, False, None),
-    ("int8", 256, 32, 8, 128, 128, 1024, True, None),
-    ("block-table-bf16", 256, 32, 8, 128, 128, 1024, False, 16),
-    ("block-table-int8", 256, 32, 8, 128, 128, 1024, True, 16),
+    ("bf16", 256, 32, 8, 128, 128, 1024, False, None, None),
+    ("bf16-ragged-bucket", 256, 32, 8, 128, 128, 1000, False, None, None),
+    ("int8", 256, 32, 8, 128, 128, 1024, True, None, None),
+    ("block-table-bf16", 256, 32, 8, 128, 128, 1024, False, 16, None),
+    ("block-table-int8", 256, 32, 8, 128, 128, 1024, True, 16, None),
     # deepseek-v2 absorbed MLA: 128 heads over one latent, d_v != d_qk
-    ("mla-absorbed", 256, 128, 1, 576, 512, 1024, False, None),
+    ("mla-absorbed", 256, 128, 1, 576, 512, 1024, False, None, None),
     # the offline cell's mixed launch: query tiles at the widest bucket
-    ("bf16-engine-T256-kv2048", 256, 32, 8, 128, 128, 2048, False, None),
+    ("bf16-engine-T256-kv2048", 256, 32, 8, 128, 128, 2048, False, None,
+     None),
+    # the model step's carried Qwen3-4B stack, read in place
+    ("bf16-stacked-36-kv2048", 256, 32, 8, 128, 128, 2048, False, None, 36),
+    ("int8-stacked-36-kv2048", 256, 32, 8, 128, 128, 2048, True, None, 36),
+    # a Qwen3-8B tp=4 shard's two kv heads
+    ("bf16-stacked-36-2-kv-heads", 256, 8, 2, 128, 128, 2048, False, None,
+     36),
 ]
 
 
 @pytest.mark.parametrize("case", KERNELS, ids=[k[0] for k in KERNELS])
 def test_packed_attention_compiles_for_v5e(one_chip, case):
-    _, t, h, kvh, dqk, dv, bucket, int8, bs = case
+    _, t, h, kvh, dqk, dv, bucket, int8, bs, layers = case
     n, s = SMOKE_SLOTS, SMOKE_MAX_LEN
+    lead = (layers,) if layers else ()
     kv_dt = jnp.int8 if int8 else jnp.bfloat16
     args = [_sds(one_chip, (t, h, dqk), jnp.bfloat16),
-            _sds(one_chip, (n, s, kvh, dqk), kv_dt),
-            _sds(one_chip, (n, s, kvh, dv), kv_dt),
+            _sds(one_chip, lead + (n, s, kvh, dqk), kv_dt),
+            _sds(one_chip, lead + (n, s, kvh, dv), kv_dt),
             _sds(one_chip, (t,), jnp.int32),
             _sds(one_chip, (t,), jnp.int32)]
     kw_names = []
+    if layers:
+        args.append(_sds(one_chip, (), jnp.int32))
+        kw_names.append("layer")
     if bs is not None:
         args.append(_sds(one_chip, (n, s // bs), jnp.int32))
         kw_names.append("block_tables")
     if int8:
-        args += [_sds(one_chip, (n, s, kvh), jnp.float32)] * 2
+        args += [_sds(one_chip, lead + (n, s, kvh), jnp.float32)] * 2
         kw_names += ["k_scale", "v_scale"]
 
     def fn(q, k, v, slot, lengths, *extra):
@@ -160,6 +172,21 @@ def test_qwen3_4b_packed_step_fits_one_v5e(one_chip, kv_dtype):
         if m.group(1) in stack_types
         and m.group(2) in ("copy", "dynamic-update-slice")]
     assert not whole_stack_ops, whole_stack_ops[:4]
+    # ... and the kernel reads the carried stack in place (DESIGN.md §9):
+    # no slice, copy or transpose cuts a layer's K/V rows out of it, in
+    # slot-major or head-major order (block-table mode: the layer's blocks)
+    n, s, kvh, hd = SMOKE_SLOTS, SMOKE_MAX_LEN, cfg.n_kv_heads, \
+        cfg.resolved_head_dim
+    rows = (((n, s), (n * s // 16, 16)) if tables is not None
+            else ((n, SMOKE_KV_BUCKET),))
+    kv_dt = _HLO_DTYPE[jax.tree.leaves(cache)[0].dtype.name]
+    layer_types = {f"{kv_dt}[{','.join(map(str, lead + shape))}]"
+                   for a, b in rows for lead in ((), (1,))
+                   for shape in ((a, b, kvh, hd), (a, kvh, b, hd))}
+    layer_ops = [
+        m.group(0) for m in _RESULT_OP.finditer(compiled.as_text())
+        if m.group(1) in layer_types and m.group(2) != "bitcast"]
+    assert not layer_ops, layer_ops[:4]
     assert mem.temp_size_in_bytes < 2**29, mem.temp_size_in_bytes / 2**30
     # ... and the donated cache is still updated in place
     assert mem.alias_size_in_bytes == cache_bytes, mem
